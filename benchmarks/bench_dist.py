@@ -1,8 +1,9 @@
 """Distributed grid: sparse ring vs dense ring vs row-parallel over
 B-density x mesh size (forced host devices).
 
-The parent process must keep seeing one device (the other benches depend
-on it), so the measured grid runs in a child interpreter with
+On a host with enough devices (a four-chip TPU host) the grid runs in
+this process.  On the CPU the parent must keep seeing one device (the
+other benches depend on it), so the grid runs in a child interpreter with
 ``--xla_force_host_platform_device_count``, exactly like the distributed
 tests.  Points are block-structured operands (the tile pipeline's regime:
 whole ``bs x bs`` tiles on/off, dense within) at several B tile densities,
@@ -123,7 +124,19 @@ def _child(n: int, mesh_sizes, densities_b, iters: int) -> None:
 
 def run(n: int = 2048, mesh_sizes=(2, 4, 8),
         densities_b=(0.02, 0.1, 0.3), iters: int = 3) -> dict:
-    """Spawn the forced-multi-device child and return the written grid."""
+    """Measure the grid (in-process, or in the forced-multi-device child
+    on the CPU) and return the written grid."""
+    from repro.tuning.probes import measure_in_process
+    from .common import results_dir
+    if measure_in_process(max(mesh_sizes)):
+        _child(n, mesh_sizes, densities_b, iters)
+    else:
+        _spawn_child(n, mesh_sizes, densities_b, iters)
+    with open(os.path.join(results_dir(), "dist_grid.json")) as f:
+        return json.load(f)
+
+
+def _spawn_child(n: int, mesh_sizes, densities_b, iters: int) -> None:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{max(mesh_sizes)} " + env.get("XLA_FLAGS", ""))
@@ -134,9 +147,6 @@ def run(n: int = 2048, mesh_sizes=(2, 4, 8),
         env=env, timeout=3600)
     if proc.returncode != 0:
         raise RuntimeError(f"bench_dist child failed: {proc.returncode}")
-    from .common import results_dir
-    with open(os.path.join(results_dir(), "dist_grid.json")) as f:
-        return json.load(f)
 
 
 def main():

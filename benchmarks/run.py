@@ -53,6 +53,8 @@ def main() -> None:
     else:
         only = set(ORDER)
 
+    from repro import compile_cache
+    print(f"[cache] compile cache at {compile_cache.enable()}", flush=True)
     from . import (bench_bc, bench_block_kernel, bench_density, bench_dist,
                    bench_health, bench_incremental, bench_ktruss,
                    bench_obs, bench_planner, bench_replay,

@@ -475,6 +475,54 @@ heap_cost = COST_HOOKS["heap"]
 heapdot_cost = COST_HOOKS["heapdot"]
 inner_cost = COST_HOOKS["inner"]
 
+# ---------------------------------------------------------------------------
+# Device workspace (planner): what a row kernel keeps live per vmapped row
+# ---------------------------------------------------------------------------
+#
+# The driver vmaps a row kernel over every row at once, so its per-row state
+# is multiplied by m.  These count bytes per row: the carried accumulator
+# state plus the widest intermediate of one step (a gathered B row is 9
+# bytes per slot: int32 col, f32 value, bool validity).  A kernel whose
+# count exceeds the device cannot run there; the planner drops it
+# (``planner.rank_algorithms``).  On a 16 GB v5e at R-MAT scale 16 that
+# removes msa (dense (n+1)-wide f32+int8 state: 21 GB), heap/heapdot (the
+# wa*wb expansion: 32 GB) and inner (a wbt-wide B^T row per mask slot: the
+# chip's compiler aborts on its 32-bit bounds long before memory runs out).
+# At scale 13 inner's count, 17.0 GB, is what the chip's compiler reports
+# for its program, and the v5e's limit is 16.9 GB.
+
+
+def _msa_workspace(*, n, wa, wb, wbt, pm):
+    return 5.0 * (n + 1) + 9.0 * wb
+
+
+def _hash_workspace(*, n, wa, wb, wbt, pm):
+    return 9.0 * _hash_size(max(1, pm)) + 9.0 * wb
+
+
+def _mca_workspace(*, n, wa, wb, wbt, pm):
+    return 5.0 * (pm + 1) + 9.0 * wb
+
+
+def _heap_workspace(*, n, wa, wb, wbt, pm):
+    return 8.0 * wa * wb
+
+
+def _inner_workspace(*, n, wa, wb, wbt, pm):
+    # per mask slot: the gathered B^T row and each A entry's search position
+    return pm * (9.0 * wbt + 4.0 * wa)
+
+
+#: algorithm name -> device bytes per vmapped row
+ROW_WORKSPACE = {
+    "msa": _msa_workspace,
+    "hash": _hash_workspace,
+    "mca": _mca_workspace,
+    "heap": _heap_workspace,
+    "heapdot": _heap_workspace,
+    "inner": _inner_workspace,
+}
+
 #: algorithms whose row kernels accept ``complement=True`` (paper Sec. 8.4:
 #: hash/MCA/inner require an explicit mask)
 SUPPORTS_COMPLEMENT = frozenset({"msa", "heap", "heapdot"})

@@ -47,7 +47,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import caches
 from repro import obs
-from repro.compat import shard_map
 
 from .formats import CSR, PaddedCSR, bcsr_row_panels, padded_from_csr
 from .masked_spgemm import MaskedSpGEMMResult, _row_fn
@@ -74,10 +73,10 @@ def _row_parallel_program(mesh: Mesh, axes: Tuple[str, ...], algorithm: str,
                      row(mcr, acr, avr, alr, Bc, Bv, Bl))
         return f(mc, ac, av, al)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec, spec, spec, spec, P(), P(), P()),
-        out_specs=(spec, spec),
+        out_specs=(spec, spec), check_vma=False,
     ))
 
 
@@ -193,10 +192,10 @@ def _ring_dense_program(mesh: Mesh, axis: str, block: int, precision):
         acc = jnp.where(occ_elem, acc, 0.0)[:m_loc, :n]
         return jnp.where(m_blk != 0, acc, 0.0).astype(a_blk.dtype)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis, None)),
-        out_specs=P(axis, None),
+        out_specs=P(axis, None), check_vma=False,
     ))
 
 
@@ -205,15 +204,18 @@ def _ring_dense_program(mesh: Mesh, axis: str, block: int, precision):
 # ---------------------------------------------------------------------------
 
 
-def _ring_stage_xla(out, a_blocks, b_blocks, rank, pa, pb, flags, *, bs):
+def _ring_stage_xla(out, a_blocks, b_blocks, chunks, *, bs):
     """One ring stage on the chunked-XLA executor: gather, batched matmul,
-    segment-add into the running panel accumulator.  Chunked like
-    ``ops._block_spgemm_xla`` so peak memory stays O(chunk * bs^2)."""
+    segment-add into the running panel accumulator.  The stage's chunked
+    worklist is read flat (padding entries have the real-bit off), in
+    pieces like ``ops._block_spgemm_xla`` so peak memory stays
+    O(piece * bs^2)."""
     from repro.kernels.masked_matmul.ops import _XLA_CHUNK_ELEMS
+    rank, pa, pb, flags = (chunks[:, i, :].reshape(-1) for i in range(4))
     ws = int(rank.shape[0])
-    chunk = max(1, _XLA_CHUNK_ELEMS // (bs * bs))
-    for s0 in range(0, ws, chunk):
-        e = min(ws, s0 + chunk)
+    piece = max(1, _XLA_CHUNK_ELEMS // (bs * bs))
+    for s0 in range(0, ws, piece):
+        e = min(ws, s0 + piece)
         real = ((flags[s0:e] >> 1) & 1).astype(jnp.float32)
         prods = jnp.einsum("wij,wjk->wik",
                            a_blocks[pa[s0:e]].astype(jnp.float32),
@@ -223,15 +225,14 @@ def _ring_stage_xla(out, a_blocks, b_blocks, rank, pa, pb, flags, *, bs):
     return out
 
 
-def _ring_stage_pallas(out, a_blocks, b_blocks, rank, pa, pb, flags, *,
-                       bs, interpret):
+def _ring_stage_pallas(out, a_blocks, b_blocks, chunks, *, bs, interpret):
     """One ring stage on the Pallas executor: the worklist covers every
     output rank (zero-fill + padding-rank entries from
-    ``build_ring_schedules``), so the kernel's output is fully defined and
+    ``build_ring_schedules``), so the replay's output is fully defined and
     adds into the running accumulator."""
-    from repro.kernels.masked_matmul.kernel import block_spgemm_kernel
-    stage = block_spgemm_kernel(a_blocks, b_blocks, rank, pa, pb, flags,
-                                out.shape[0], bs=bs, interpret=interpret)
+    from repro.kernels.masked_matmul.ops import replay_chunks
+    stage = replay_chunks(jnp.zeros_like(out), a_blocks, b_blocks, chunks,
+                          bs=bs, interpret=interpret)
     return out + stage
 
 
@@ -261,10 +262,9 @@ def _ring_sparse_program(mesh: Mesh, axis: str, p: int, bs: int,
         panel = jnp.stack([bv, bp])        # values+pattern rotate together
 
         def compute(s, vals, cnts, pan):
-            row = jax.lax.dynamic_index_in_dim(sc, s, 0, keepdims=False)
-            rank, pa, pb, flags = row[0], row[1], row[2], row[3]
-            vals = apply_stage(vals, av, pan[0], rank, pa, pb, flags)
-            cnts = apply_stage(cnts, ap, pan[1], rank, pa, pb, flags)
+            chunks = jax.lax.dynamic_index_in_dim(sc, s, 0, keepdims=False)
+            vals = apply_stage(vals, av, pan[0], chunks)
+            cnts = apply_stage(cnts, ap, pan[1], chunks)
             return vals, cnts
 
         def stage(s, carry):
@@ -294,10 +294,10 @@ def _ring_sparse_program(mesh: Mesh, axis: str, p: int, bs: int,
         return out_v, out_p
 
     spec = P(axis)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec,) * 10,
-        out_specs=(spec, spec)))
+        out_specs=(spec, spec), check_vma=False))
 
 
 def _panel_scatter(x: CSR, bs: int, p: int) -> Tuple[np.ndarray, ...]:
@@ -448,7 +448,7 @@ def ring_sparse_masked_spgemm(A: CSR, B: CSR, M: CSR, mesh: Mesh, *,
     accumulate with a dense dot) — ``distributed_masked_spgemm`` routes
     unsupported products to the row-parallel path.
     """
-    from repro.kernels.masked_matmul.ops import on_tpu
+    from repro.kernels.masked_matmul.ops import resolve_executor
 
     m, k = A.shape
     k2, n = B.shape
@@ -465,13 +465,7 @@ def ring_sparse_masked_spgemm(A: CSR, B: CSR, M: CSR, mesh: Mesh, *,
         from .planner import ring_block_candidates
         block_size = ring_block_candidates(m, k, n)[0]
     bs = block_size
-    if backend is None:
-        backend = "pallas" if (interpret or on_tpu()) else "xla"
-    it = None
-    if backend == "pallas":
-        it = (not on_tpu()) if interpret is None else interpret
-    elif backend != "xla":
-        raise ValueError(f"unknown backend {backend!r}")
+    backend, it = resolve_executor(backend, interpret)
 
     prep = _ring_prep(A, B, M, bs, p, wm)
     a_pan, a_loc, a_r, a_c, wa = prep["a_scatter"]
@@ -550,20 +544,22 @@ def distributed_masked_spgemm(A: CSR, B: CSR, M: CSR, mesh: Mesh, *,
             row_algorithm = dplan.row_algorithm
 
     if algorithm == "ring":
+        from repro.kernels.masked_matmul.ops import resolve_executor
+        backend, interpret = resolve_executor(backend, interpret)
         with obs.span("spgemm.dist", route="ring", p=p,
-                      block=block_size or 0):
+                      block=block_size or 0, executor=backend,
+                      interpret=interpret):
             return ring_sparse_masked_spgemm(
                 A, B, M, mesh, axis=axis, block_size=block_size,
                 backend=backend, interpret=interpret)
 
     # row-parallel: replicate B, shard A/M rows, run the row kernels
+    m, n = M.shape
     if row_algorithm is None:
-        from .planner import decide, collect_stats
+        from .planner import collect_stats, rank_algorithms
         stats = collect_stats(A, B, M, complement=complement,
                               semiring=semiring)
-        dec = decide(stats, allow_tile=False)
-        row_algorithm = dec.algorithm
-    m, n = M.shape
+        row_algorithm = rank_algorithms(stats, rows=-(-m // p))[0][0]
     with obs.span("spgemm.dist", route="row", p=p,
                   algorithm=row_algorithm):
         with obs.span("spgemm.host_prep", algorithm=row_algorithm):

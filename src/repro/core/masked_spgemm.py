@@ -255,7 +255,8 @@ def _masked_spgemm_tile(A: CSR, B: CSR, M: CSR, *,
     exact element-level structure — bitwise the row kernels' semantics,
     including numeric-cancellation cases.
     """
-    from repro.kernels.masked_matmul.ops import block_spgemm_with_structure
+    from repro.kernels.masked_matmul.ops import (block_spgemm_with_structure,
+                                                 resolve_executor)
 
     m, k = A.shape
     _, n = B.shape
@@ -268,7 +269,9 @@ def _masked_spgemm_tile(A: CSR, B: CSR, M: CSR, *,
         from .planner import ring_block_candidates
         block_size = ring_block_candidates(m, k, n)[0]
     bs = block_size
-    with obs.span("spgemm.tile", block=bs, m=m, n=n):
+    backend, interpret = resolve_executor(backend, interpret)
+    with obs.span("spgemm.tile", block=bs, m=m, n=n, executor=backend,
+                  interpret=interpret):
         with obs.span("spgemm.host_prep", algorithm="tile"):
             Ab = bcsr_from_csr(A, bs)
             Bb = bcsr_from_csr(B, bs)

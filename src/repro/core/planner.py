@@ -145,6 +145,9 @@ class PlanStats:
     #: B^T and a padded B cannot be transposed without a host round-trip,
     #: so it must not be auto-selected (the driver would misread B as B^T)
     b_transposable: bool = True
+    #: bytes one program may hold on the device (``device_bytes_limit``);
+    #: None where the backend reports no limit (the CPU)
+    mem_limit: Optional[int] = None
 
     @property
     def compression(self) -> float:
@@ -224,6 +227,17 @@ def symbolic_probe(A: CSR, B: CSR, M: CSR, *, complement: bool = False,
     return flops * scale, out * scale
 
 
+def device_bytes_limit() -> Optional[int]:
+    """``bytes_limit`` of the default device's memory stats: what one
+    program may hold there (about 15.7 GB on a 16 GB v5e).  None where the
+    backend reports no memory stats, as the CPU does."""
+    import jax
+    stats = jax.devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"])
+
+
 def collect_stats(A: CSR, B: CSR, M: CSR, *, complement: bool = False,
                   semiring: Semiring = PLUS_TIMES,
                   probe: bool = True) -> PlanStats:
@@ -236,7 +250,7 @@ def collect_stats(A: CSR, B: CSR, M: CSR, *, complement: bool = False,
         m=m, k=k, n=n, nnz_a=A.nnz, nnz_b=B.nnz, nnz_m=M.nnz,
         wa=_max_row_nnz(A), wb=_max_row_nnz(B), wbt=_max_col_nnz(B),
         pm=_max_row_nnz(M), complement=complement, semiring=semiring.name,
-        flops=flops, out_nnz=out_nnz)
+        flops=flops, out_nnz=out_nnz, mem_limit=device_bytes_limit())
 
 
 # ---------------------------------------------------------------------------
@@ -244,13 +258,31 @@ def collect_stats(A: CSR, B: CSR, M: CSR, *, complement: bool = False,
 # ---------------------------------------------------------------------------
 
 
-def rank_algorithms(stats: PlanStats) -> Tuple[Tuple[str, float], ...]:
+def rank_algorithms(stats: PlanStats, *, rows: Optional[int] = None
+                    ) -> Tuple[Tuple[str, float], ...]:
     """Per-algorithm cost estimates (ms for the whole product), cheapest
-    first.  Pure function of ``stats``."""
+    first.  Pure function of ``stats``.
+
+    With ``stats.mem_limit`` set, a kernel whose workspace
+    (``accumulators.ROW_WORKSPACE``) times the ``rows`` vmapped at once
+    (default: all ``stats.m``; one shard's rows on a mesh) exceeds it is
+    left out: it cannot run on the device however cheap it is modeled.
+    Raises ``ValueError`` when no kernel fits.
+    """
     candidates = [a for a in CANDIDATES
                   if not stats.complement or a in acc.SUPPORTS_COMPLEMENT]
     if not stats.b_transposable:
         candidates = [a for a in candidates if a != "inner"]
+    if stats.mem_limit is not None:
+        rows = stats.m if rows is None else rows
+        fits = [a for a in candidates if rows * acc.ROW_WORKSPACE[a](
+            n=stats.n, wa=stats.wa, wb=stats.wb, wbt=stats.wbt,
+            pm=stats.pm) <= stats.mem_limit]
+        if not fits:
+            raise ValueError(
+                f"no row kernel fits {rows} rows in {stats.mem_limit} "
+                f"device bytes (candidates: {candidates})")
+        candidates = fits
     scale = stats.m / 1024.0
     costs = []
     for name in candidates:
@@ -453,7 +485,7 @@ def _distributed_decision(stats: PlanStats, p: int
     """(costs, row_algorithm, ring tile_block) — each modeled exactly once.
     """
     from repro.kernels.masked_matmul.ops import tile_path_supported
-    row_alg, row_compute = rank_algorithms(stats)[0]
+    row_alg, row_compute = rank_algorithms(stats, rows=-(-stats.m // p))[0]
     costs = [("row", row_compute / p + DIST_COST["per_bcast_elem"]
               * row_replication_elems(stats, row_alg))]
     tile_block = 0

@@ -82,7 +82,7 @@ def masked_matmul_kernel(a, b, bi, bj, *, bm, bn, bk, out_dtype=jnp.float32,
 
 
 def _block_spgemm_body(rank_ref, pa_ref, pb_ref, flags_ref,
-                       a_ref, b_ref, o_ref, acc_ref):
+                       a_ref, b_ref, _carried_ref, o_ref, acc_ref):
     w = pl.program_id(0)
     first = flags_ref[w] & 1
     real = (flags_ref[w] >> 1) & 1
@@ -102,17 +102,20 @@ def _block_spgemm_body(rank_ref, pa_ref, pb_ref, flags_ref,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)[None]
 
 
-def block_spgemm_kernel(a_blocks, b_blocks, rank, pa, pb, flags, nnzb_out,
-                        *, bs, out_dtype=jnp.float32, interpret=False):
-    """Masked BCSR product from a worklist.
+def block_spgemm_kernel(a_blocks, b_blocks, rank, pa, pb, flags, out,
+                        *, bs, interpret=False):
+    """Masked BCSR product from one worklist chunk, replayed into ``out``.
 
     a_blocks: (nnzb_a, bs, bs); b_blocks: (nnzb_b, bs, bs).
     rank/pa/pb: (W,) int32 — output block rank and A/B block positions.
     flags: (W,) int32 bitfield — 1=first visit of rank, 2=real product
       (0 -> zero-fill entry for a mask block with no contribution),
       4=last visit of rank (flush accumulator to HBM).
-    The worklist MUST be sorted by rank (sequential-grid accumulation).
-    Returns (nnzb_out, bs, bs).
+    The worklist MUST be sorted by rank (sequential-grid accumulation) and
+    must not end inside a rank.  ``out`` (nnzb_out, bs, bs) f32 is aliased
+    to the result: blocks the chunk does not visit keep their values, which
+    is what lets ``ops.replay_chunks`` split a long worklist into calls
+    whose scalar-prefetch arrays fit in SMEM.
     """
     W = rank.shape[0]
     return pl.pallas_call(
@@ -125,12 +128,15 @@ def block_spgemm_kernel(a_blocks, b_blocks, rank, pa, pb, flags, nnzb_out,
                              lambda w, r_r, pa_r, pb_r, f_r: (pa_r[w], 0, 0)),
                 pl.BlockSpec((1, bs, bs),
                              lambda w, r_r, pa_r, pb_r, f_r: (pb_r[w], 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),   # aliased, never read
             ],
             out_specs=pl.BlockSpec((1, bs, bs),
                                    lambda w, r_r, pa_r, pb_r, f_r:
                                    (r_r[w], 0, 0)),
             scratch_shapes=[pltpu.VMEM((bs, bs), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((nnzb_out, bs, bs), out_dtype),
+        out_shape=jax.ShapeDtypeStruct(out.shape, out.dtype),
+        # operand 6 = ``out`` (the four scalar-prefetch arrays count)
+        input_output_aliases={6: 0},
         interpret=interpret,
-    )(rank, pa, pb, flags, a_blocks, b_blocks)
+    )(rank, pa, pb, flags, a_blocks, b_blocks, out)

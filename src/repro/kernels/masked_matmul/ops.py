@@ -17,10 +17,12 @@ Two executors replay the worklist:
   XLA.  The fast path on CPU/GPU where Pallas interpret mode would be pure
   Python overhead.
 
-``backend=None`` picks pallas on TPU and xla elsewhere, re-queried per call
-(the backend can change mid-process, e.g. tests forcing CPU after a TPU
-probe — caching the first answer forever ran compiled-mode kernels in the
-wrong mode).
+``backend=None`` picks pallas on TPU and xla elsewhere (``resolve_executor``),
+re-queried per call (the backend can change mid-process, e.g. tests forcing
+CPU after a TPU probe — caching the first answer forever ran compiled-mode
+kernels in the wrong mode).  The Pallas executor replays long worklists in
+chunks of ``SPGEMM_CHUNK`` entries, the most one kernel call's
+scalar-prefetch arrays can hold in SMEM with room to spare.
 """
 from __future__ import annotations
 
@@ -57,11 +59,37 @@ def tile_path_supported(semiring_name: str, complement: bool) -> bool:
     return semiring_name == "plus_times" and not complement
 
 
+def _check_tpu_tiling(a_shape, b_shape, bm: int, bn: int, bk: int) -> None:
+    """Raise ``ValueError`` unless Mosaic can tile ``masked_matmul``'s blocks.
+
+    The TPU tiles the last two dims of every block by (8, 128) f32 words,
+    so each block dim must be a multiple of its tile or span the whole
+    array dim: A's (bm, bk) block of (M, K) and B's (bk, bn) block of
+    (K, N).  (The output's (bm, bn) block always spans its array dims.)
+    """
+    (M, K), (_, N) = a_shape, b_shape
+    bad = [f"{name}={blk} (multiple of {tile} or {full} needed)"
+           for name, blk, tile, full in (("bm", bm, 8, M), ("bk", bk, 8, K),
+                                         ("bk", bk, 128, K),
+                                         ("bn", bn, 128, N))
+           if blk % tile and blk != full]
+    if bad:
+        raise ValueError("the TPU cannot tile these masked_matmul blocks: "
+                         + ", ".join(bad))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def masked_matmul(a, b, bi, bj, *, bm, bn, bk, interpret=None):
-    """Tile-MCA SDDMM: only mask-allowed output tiles are computed."""
+    """Tile-MCA SDDMM: only mask-allowed output tiles are computed.
+
+    Compiled for the chip (``interpret=False``, the default on TPU), the
+    block shape must be one the TPU can tile, or ``ValueError`` is raised
+    before the compiler sees the kernel.
+    """
     interpret = (not on_tpu()) if interpret is None else interpret
+    if not interpret:
+        _check_tpu_tiling(a.shape, b.shape, bm, bn, bk)
     return masked_matmul_kernel(a, b, bi, bj, bm=bm, bn=bn, bk=bk,
                                 interpret=interpret)
 
@@ -199,24 +227,22 @@ def build_spgemm_schedule_slab(A: BCSR, B_slab: BCSR, M: BCSR,
 
 def build_ring_schedules(A_panels, B_slabs, M_panels, *, out_pad: int
                          ) -> np.ndarray:
-    """Stacked per-device, per-stage worklists for the sparse ring.
+    """Stacked per-device, per-stage chunked worklists for the sparse ring.
 
-    Returns int32 ``(p, p, 4, Ws)``: ``[d, s]`` is the worklist
-    ``(rank, pa, pb, flags)`` device ``d`` replays at ring stage ``s``,
-    when it holds B K-slab ``(d - s) % p``.  All worklists are padded to
-    one static length ``Ws``:
-
-    * ranks ``[nnzb(M_panel), out_pad)`` (the ring-wide output padding) get
-      zero-fill entries (flags first|last, real off) so per-stage executors
-      that require every output rank to be written stay fully defined;
-    * trailing padding entries carry ``rank = out_pad - 1`` with all flags
-      off (no write, no contribution) so rank-sortedness is preserved.
+    Returns int32 ``(p, p, n_chunks, 4, length)``: ``[d, s]`` holds the
+    worklist ``(rank, pa, pb, flags)`` device ``d`` replays at ring stage
+    ``s``, when it holds B K-slab ``(d - s) % p``, split by
+    ``chunk_schedule`` into chunks of one static length.  Ranks
+    ``[nnzb(M_panel), out_pad)`` (the ring-wide output padding) get
+    zero-fill entries (flags first|last, real off), so every output rank
+    of every stage is written.  Stages with fewer chunks than the ring's
+    longest are padded with chunks whose flags are all off, which
+    ``replay_chunks`` never launches.
     """
     p = len(A_panels)
     assert len(B_slabs) == len(M_panels) == p
     slab_rows = B_slabs[0].block_rows
     scheds = {}
-    ws = 1
     for d in range(p):
         for s in range(p):
             src = (d - s) % p
@@ -232,13 +258,12 @@ def build_ring_schedules(A_panels, B_slabs, M_panels, *, out_pad: int
                 flags = np.concatenate([flags, np.full(len(extra), 5,
                                                        np.int32)])
             scheds[d, s] = (rank, pa, pb, flags)
-            ws = max(ws, len(rank))
-    out = np.zeros((p, p, 4, ws), np.int32)
-    out[:, :, 0, :] = max(0, out_pad - 1)
-    for (d, s), parts in scheds.items():
-        L = len(parts[0])
-        for i, arr in enumerate(parts):
-            out[d, s, i, :L] = arr
+    length = min(SPGEMM_CHUNK, max(len(w[0]) for w in scheds.values()))
+    chunked = {ds: chunk_schedule(w, length) for ds, w in scheds.items()}
+    n_chunks = max(len(c) for c in chunked.values())
+    out = np.zeros((p, p, n_chunks, 4, length), np.int32)
+    for (d, s), c in chunked.items():
+        out[d, s, :len(c)] = c
     return out
 
 
@@ -247,12 +272,70 @@ def build_ring_schedules(A_panels, B_slabs, M_panels, *, out_pad: int
 # ---------------------------------------------------------------------------
 
 
+#: worklist entries one ``block_spgemm_kernel`` call replays.  The call's
+#: four scalar-prefetch arrays take 16 bytes per entry of the TPU core's
+#: 1 MiB SMEM: on v5e a 65,536-entry call fails to compile (out of SMEM)
+#: and 60,000 entries compile.  16,384 entries (256 KiB) leave three
+#: quarters of SMEM to Mosaic, and one chunk is ~16k grid steps, so the
+#: per-call launch cost stays negligible.
+SPGEMM_CHUNK = 16384
+
+
+def chunk_schedule(schedule: Schedule, length: int) -> np.ndarray:
+    """Split a rank-sorted worklist into int32 ``(n_chunks, 4, length)``.
+
+    Chunks end only at rank boundaries, so every rank's first and last
+    visit stay inside one kernel call and the flags keep their meaning.
+    The tail of each chunk repeats its last rank with all flags off: those
+    steps neither zero, accumulate nor flush, and the block they revisit
+    is the one the chunk just flushed.  Raises ``ValueError`` when one
+    rank alone has more than ``length`` entries.
+    """
+    rank = schedule[0]
+    W = len(rank)
+    starts = np.concatenate([[0], np.flatnonzero(np.diff(rank)) + 1])
+    cuts = [0]
+    while cuts[-1] + length < W:
+        i = np.searchsorted(starts, cuts[-1] + length, side="right") - 1
+        if starts[i] <= cuts[-1]:
+            raise ValueError(
+                f"output block rank {int(rank[cuts[-1]])} has more than "
+                f"{length} worklist entries; it cannot fit in one chunk")
+        cuts.append(int(starts[i]))
+    cuts = np.asarray(cuts + [W])
+    lens = np.diff(cuts)
+    out = np.zeros((len(lens), 4, length), np.int32)
+    out[:, 0, :] = rank[cuts[1:] - 1][:, None]
+    chunk_of = np.repeat(np.arange(len(lens)), lens)
+    pos = np.arange(W) - np.repeat(cuts[:-1], lens)
+    for i, arr in enumerate(schedule):
+        out[chunk_of, i, pos] = arr
+    return out
+
+
+def replay_chunks(out, a_blocks, b_blocks, chunks, *, bs, interpret):
+    """Replay a chunked worklist (``chunk_schedule``) into ``out`` on the
+    Pallas kernel, one call per chunk, each call aliasing ``out`` so the
+    blocks it does not visit survive.  Chunks whose flags are all off
+    (the ring's stage padding) are never launched: a Pallas output block
+    is written back on every visit, even one whose body never stored."""
+    n_real = jnp.sum(jnp.any(chunks[:, 3, :] != 0, axis=1))
+
+    def body(c, out):
+        rank, pa, pb, flags = (chunks[c, i] for i in range(4))
+        return block_spgemm_kernel(a_blocks, b_blocks, rank, pa, pb, flags,
+                                   out, bs=bs, interpret=interpret)
+
+    return jax.lax.fori_loop(0, n_real, body, out)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("nnzb_out", "bs", "interpret"))
-def _block_spgemm_pallas(a_blocks, b_blocks, rank, pa, pb, flags, *,
-                         nnzb_out, bs, interpret):
-    return block_spgemm_kernel(a_blocks, b_blocks, rank, pa, pb, flags,
-                               nnzb_out, bs=bs, interpret=interpret)
+def _block_spgemm_pallas(a_blocks, b_blocks, chunks, *, nnzb_out, bs,
+                         interpret):
+    out = jnp.zeros((nnzb_out, bs, bs), jnp.float32)
+    return replay_chunks(out, a_blocks, b_blocks, chunks, bs=bs,
+                         interpret=interpret)
 
 
 @jax.jit
@@ -301,31 +384,46 @@ def _block_spgemm_xla(a_blocks, b_blocks, rank, pa, pb, flags, *,
     return out
 
 
+def resolve_executor(backend: Optional[str], interpret: Optional[bool]
+                     ) -> Tuple[str, Optional[bool]]:
+    """The worklist executor a call runs on: ``(backend, interpret)``.
+
+    ``backend=None`` picks ``"pallas"`` on TPU and ``"xla"`` elsewhere;
+    ``interpret=True`` requests the Pallas kernel in interpret mode (the
+    CPU tests), while ``interpret=False`` only means "compiled mode if
+    Pallas runs at all" — off the TPU it still picks XLA, never
+    compiled-mode Mosaic on a host platform.  ``interpret`` comes back
+    resolved for Pallas and ``None`` for XLA.  Callers record the pair on
+    their span, so a trace shows which executor ran.
+    """
+    if backend is None:
+        backend = "pallas" if (interpret or on_tpu()) else "xla"
+    if backend == "pallas":
+        return backend, (not on_tpu()) if interpret is None else interpret
+    if backend == "xla":
+        return backend, None
+    raise ValueError(f"unknown backend {backend!r}")
+
+
 def _run_schedule(A: BCSR, B: BCSR, M: BCSR, schedule: Schedule,
                   blocks_a, blocks_b, *, interpret, backend):
     bs = A.block_size
-    if backend is None:
-        # interpret=True requests the pallas path (tests exercise the kernel
-        # in interpret mode on CPU); interpret=False only means "compiled
-        # mode *if* pallas runs at all" — off-TPU it must still pick xla,
-        # never compiled-mode Mosaic on a host platform
-        backend = "pallas" if (interpret or on_tpu()) else "xla"
+    backend, interpret = resolve_executor(backend, interpret)
     # an empty operand leaves only zero-fill entries in the worklist, but
     # those still address block 0 — give them one zero block to read
     if blocks_a.shape[0] == 0:
         blocks_a = jnp.zeros((1, bs, bs), blocks_a.dtype)
     if blocks_b.shape[0] == 0:
         blocks_b = jnp.zeros((1, bs, bs), blocks_b.dtype)
-    rank, pa, pb, flags = (jnp.asarray(x) for x in schedule)
     if backend == "pallas":
-        interpret = (not on_tpu()) if interpret is None else interpret
-        return _block_spgemm_pallas(blocks_a, blocks_b, rank, pa, pb, flags,
+        chunks = chunk_schedule(schedule,
+                                min(SPGEMM_CHUNK, len(schedule[0])))
+        return _block_spgemm_pallas(blocks_a, blocks_b, jnp.asarray(chunks),
                                     nnzb_out=M.nnzb, bs=bs,
                                     interpret=interpret)
-    if backend == "xla":
-        return _block_spgemm_xla(blocks_a, blocks_b, rank, pa, pb, flags,
-                                 nnzb_out=M.nnzb, bs=bs)
-    raise ValueError(f"unknown backend {backend!r}")
+    rank, pa, pb, flags = (jnp.asarray(x) for x in schedule)
+    return _block_spgemm_xla(blocks_a, blocks_b, rank, pa, pb, flags,
+                             nnzb_out=M.nnzb, bs=bs)
 
 
 def block_spgemm(A: BCSR, B: BCSR, M: BCSR, *, interpret=None,

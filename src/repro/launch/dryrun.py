@@ -16,7 +16,6 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.configs.base import (ARCH_IDS, SHAPES, get_config,  # noqa: E402
                                 cell_is_runnable)
-from repro.compat import set_mesh  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.specs import (train_input_specs,  # noqa: E402
                                 decode_input_specs)
@@ -206,7 +205,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     opt = AdamW()
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind in ("train", "prefill"):
             state_shapes = jax.eval_shape(
                 lambda: init_state(cfg, jax.random.PRNGKey(0), opt))

@@ -18,7 +18,6 @@ import time
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import set_mesh
 from repro.configs.base import get_config
 from repro.data.pipeline import SyntheticLM, batch_for
 from repro.launch.mesh import make_production_mesh, make_elastic_mesh
@@ -42,7 +41,7 @@ def run(arch: str, *, smoke: bool, steps: int, batch: int, seq: int,
     opt = AdamW(lr=lr, warmup=min(20, steps // 5 + 1), total_steps=steps)
     pipe = SyntheticLM(cfg.vocab_size, seq, batch, seed=seed)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_state(cfg, jax.random.PRNGKey(seed), opt)
         sshapes = jax.eval_shape(lambda: state)
         sspec = state_specs(cfg, sshapes, zero1=True)

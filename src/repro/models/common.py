@@ -14,8 +14,7 @@ TP = "model"             # tensor/expert-parallel axis
 
 def _mesh_axis_names():
     try:
-        from repro.compat import get_abstract_mesh
-        mesh = get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         return tuple(mesh.axis_names) if mesh is not None else ()
     except Exception:
         return ()

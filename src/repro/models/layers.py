@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig, MoECfg, MLACfg
 from .attention import attention, decode_attention
 from .common import dense_init, rms_norm, layer_norm, rope, shard, DP, TP
@@ -381,8 +380,7 @@ def _apply_moe_ep(params, cfg: ModelConfig, x, axis_names):
     """Expert-parallel MoE: shard_map over (dp..., model)."""
     mo: MoECfg = cfg.moe
     b, s, d = x.shape
-    from repro.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     from jax.sharding import PartitionSpec as P
     dp = tuple(a for a in ("pod", "data") if a in axis_names)
     ep = mesh.shape["model"]
@@ -438,12 +436,12 @@ def _apply_moe_ep(params, cfg: ModelConfig, x, axis_names):
         combined = jax.lax.psum(combined, "model")
         return combined.reshape(bl, s, d)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp if dp else None, None, None), P(),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
-        out_specs=P(dp if dp else None, None, None))
+        out_specs=P(dp if dp else None, None, None), check_vma=False)
     out = fn(x, params["router"],
              params["experts_gate"], params["experts_up"],
              params["experts_down"]).astype(x.dtype)

@@ -29,10 +29,9 @@ def allreduce_compressed(grads, error, axis_names: Sequence[str]):
     quantization: every shard uses the same (pmax-agreed) scale, so the
     summed int payload dequantizes exactly to sum(q_i)*scale.
     """
-    from repro.compat import axis_size
     n = 1
     for a in axis_names:
-        n = n * axis_size(a)
+        n = n * jax.lax.axis_size(a)
 
     def one(g, e):
         x = g.astype(jnp.float32) + e

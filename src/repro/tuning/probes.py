@@ -252,15 +252,32 @@ def _measure_dist(n: int, mesh_sizes: Sequence[int],
     return out
 
 
-def probe_dist(*, smoke: bool = False, log=print) -> List[Measurement]:
-    """Distributed probes; spawns a forced-host-device child interpreter
-    when this process sees fewer devices than the largest probed mesh
-    (jax's device count is frozen at first use and cannot be raised
-    in-process)."""
+def measure_in_process(need: int) -> bool:
+    """Whether a ``need``-device measurement runs in this process.
+
+    True when the process already sees that many devices.  False on the
+    CPU, where a child interpreter with
+    ``--xla_force_host_platform_device_count`` stands in (the device count
+    is frozen at first use and cannot be raised in-process).  On an
+    accelerator with fewer devices it raises: a child would need the chip
+    this process already holds, and would fail or hang.
+    """
     import jax
 
+    have = len(jax.devices())
+    if have >= need:
+        return True
+    if jax.default_backend() == "cpu":
+        return False
+    raise RuntimeError(f"{need} devices needed; this host has {have} "
+                       f"{jax.default_backend()} device(s)")
+
+
+def probe_dist(*, smoke: bool = False, log=print) -> List[Measurement]:
+    """Distributed probes: in-process when this process sees the largest
+    probed mesh, otherwise (CPU only) in a forced-host-device child."""
     spec = _dist_spec(smoke)
-    if len(jax.devices()) >= max(spec["mesh_sizes"]):
+    if measure_in_process(max(spec["mesh_sizes"])):
         return _measure_dist(log=log, **spec)
 
     out_path = os.path.join(os.environ.get("TMPDIR", "/tmp"),

@@ -14,7 +14,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.compat import set_mesh  # noqa: E402
 from repro.core.formats import (  # noqa: E402
     csr_from_dense, padded_from_csr)
 from repro.core.distributed import (  # noqa: E402
@@ -101,7 +100,7 @@ def moe_ep_check():
                     jnp.float32) * 0.3
     dense = Lyr._apply_moe_dense(params, cfg, x)
     mesh = jax.make_mesh((4, 2), ("data", "model"))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         ep = jax.jit(lambda p, xx: Lyr.apply_moe(p, cfg, xx))(params, x)
     np.testing.assert_allclose(np.asarray(ep), np.asarray(dense),
                                rtol=2e-4, atol=2e-5)

@@ -71,6 +71,32 @@ def ring_vs_oracle_over_meshes():
     print("ring_vs_oracle OK")
 
 
+def ring_pallas_chunked():
+    """The ring's Pallas stages (interpret mode) replay chunked worklists:
+    with chunks far shorter than a stage's worklist, and stages padded
+    with never-launched chunks, the result stays bitwise."""
+    from repro.core.distributed import clear_ring_prep_cache
+    from repro.kernels.masked_matmul import ops
+    full = ops.SPGEMM_CHUNK
+    A = int_sparse(48, 40, 0.3)
+    B = int_sparse(40, 56, 0.3)
+    M = (rng.random((48, 56)) < 0.5).astype(np.float32)
+    try:
+        for chunk in (full, 16):
+            ops.SPGEMM_CHUNK = chunk
+            clear_ring_prep_cache()
+            for p in (2, 4):
+                out = ring_sparse_masked_spgemm(
+                    csr_from_dense(A), csr_from_dense(B), csr_from_dense(M),
+                    mesh_of(p), block_size=8, backend="pallas",
+                    interpret=True)
+                check_bitwise(out, A, B, M)
+    finally:
+        ops.SPGEMM_CHUNK = full
+        clear_ring_prep_cache()
+    print("ring_pallas_chunked OK")
+
+
 def ring_empty_mask_and_empty_slabs():
     mesh = mesh_of(8)
     # empty mask: defined degenerate, no kernel work
@@ -159,6 +185,7 @@ def entry_point_routes_and_matches():
 def main():
     assert jax.device_count() == 8, jax.devices()
     ring_vs_oracle_over_meshes()
+    ring_pallas_chunked()
     ring_empty_mask_and_empty_slabs()
     ring_never_densifies()
     entry_point_routes_and_matches()

@@ -8,7 +8,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.compat import set_mesh  # noqa: E402
 from repro.configs.base import get_config, ShapeCfg  # noqa: E402
 from repro.launch.dryrun import cache_specs, collective_bytes  # noqa: E402
 from repro.launch.specs import (train_input_specs,  # noqa: E402
@@ -33,7 +32,7 @@ def main():
     opt = AdamW()
     for arch in ARCHS:
         cfg = get_config(arch, smoke=True)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             # train
             state_shapes = jax.eval_shape(
                 lambda: init_state(cfg, jax.random.PRNGKey(0), opt))

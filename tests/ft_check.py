@@ -21,7 +21,6 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.compat import set_mesh  # noqa: E402
 from repro.configs.base import get_config  # noqa: E402
 from repro.data.pipeline import SyntheticLM, batch_for  # noqa: E402
 from repro.launch.mesh import make_elastic_mesh  # noqa: E402
@@ -43,7 +42,7 @@ def main():
     pipe = SyntheticLM(cfg.vocab_size, 16, 8, seed=11)
     mesh = make_elastic_mesh(n_dev, model_parallel=2)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_state(cfg, jax.random.PRNGKey(7), opt)
         sshapes = jax.eval_shape(lambda: state)
         sspec = state_specs(cfg, sshapes, zero1=True)

@@ -13,6 +13,8 @@ from repro.core.planner import (PlanStats, clear_plan_cache, collect_stats,
                                 rank_algorithms)
 from repro.core.semiring import MIN_PLUS, PLUS_TIMES
 
+from conftest import SCALE16_TC
+
 
 def stats(**kw):
     base = dict(m=1024, k=1024, n=1024, nnz_a=9000, nnz_b=9000, nnz_m=9000,
@@ -69,6 +71,35 @@ def test_complement_restricts_candidates():
 def test_ranking_covers_all_algorithms():
     ranked = [a for a, _ in rank_algorithms(stats())]
     assert sorted(ranked) == sorted(ALGORITHMS)
+
+
+def test_memory_limit_drops_kernels_that_cannot_fit():
+    """On a 16 GB chip the scale-16 triangle count must not elect a kernel
+    whose vmapped workspace exceeds the device: inner (modeled cheapest)
+    and msa (21 GB dense state) are dropped, hash/mca remain; on a 4-way
+    mesh each shard holds a quarter of the rows, so msa fits again."""
+    unlimited = stats(**SCALE16_TC)
+    assert decide(unlimited).algorithm == "inner"
+    chip = stats(**SCALE16_TC, mem_limit=16 * 10 ** 9)
+    ranked = [a for a, _ in rank_algorithms(chip)]
+    assert set(ranked) == {"hash", "mca"}
+    assert decide(chip).algorithm in ("hash", "mca")
+    sharded = [a for a, _ in rank_algorithms(chip, rows=65536 // 4)]
+    assert "msa" in sharded and "inner" not in sharded
+    with pytest.raises(ValueError, match="no row kernel fits"):
+        rank_algorithms(stats(**SCALE16_TC, mem_limit=10 ** 6))
+
+
+@pytest.mark.parametrize("scale,inner_fits", [(12, True), (13, False)])
+def test_memory_limit_on_inner_follows_the_chip_compiler(scale, inner_fits):
+    """Compiled for a v5e, inner's program for the R-MAT triangle count
+    takes 4.0 GB at scale 12 and 17.0 GB at scale 13, against the chip's
+    16.9 GB ``bytes_limit``: the planner keeps it only where it fits."""
+    from repro.core.formats import tril
+    from repro.graphs.triangle_counting import degree_relabel
+    L = tril(degree_relabel(rmat(scale, 16, seed=1)), strict=True)
+    s = dataclasses.replace(collect_stats(L, L, L), mem_limit=16_909_336_064)
+    assert ("inner" in dict(rank_algorithms(s))) is inner_fits
 
 
 # ---- tile-path eligibility ------------------------------------------------

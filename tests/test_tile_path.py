@@ -115,6 +115,62 @@ def test_xla_and_pallas_executors_agree():
                                   np.asarray(pallas.blocks))
 
 
+@pytest.mark.parametrize("chunk", [1, 8, 64])
+def test_chunked_pallas_replay_matches_xla(monkeypatch, chunk):
+    """A worklist longer than one kernel call's chunk is replayed in
+    several aliased calls; blocks a call does not visit must survive, so
+    the result equals the one-call replay and the XLA executor."""
+    rng = np.random.default_rng(3)
+    A = bcsr_from_csr(csr_from_dense(int_sparse(rng, 32, 32, 0.4)), 4)
+    M = bcsr_from_csr(csr_from_dense(int_sparse(rng, 32, 32, 0.6)), 4)
+    want = block_spgemm(A, A, M, backend="xla")
+    one_call = block_spgemm(A, A, M, backend="pallas", interpret=True)
+    monkeypatch.setattr(ops, "SPGEMM_CHUNK", chunk)
+    if chunk == 1:   # a rank with two entries cannot fit one entry
+        with pytest.raises(ValueError, match="cannot fit in one chunk"):
+            block_spgemm(A, A, M, backend="pallas", interpret=True)
+        return
+    got = block_spgemm(A, A, M, backend="pallas", interpret=True)
+    np.testing.assert_array_equal(np.asarray(got.blocks),
+                                  np.asarray(want.blocks))
+    np.testing.assert_array_equal(np.asarray(got.blocks),
+                                  np.asarray(one_call.blocks))
+
+
+def test_chunk_schedule_splits_at_rank_boundaries():
+    rng = np.random.default_rng(4)
+    A = bcsr_from_csr(csr_from_dense(int_sparse(rng, 40, 40, 0.4)), 4)
+    sched = build_spgemm_schedule(A, A, A)
+    chunks = ops.chunk_schedule(sched, 16)
+    assert chunks.shape[1:] == (4, 16) and chunks.dtype == np.int32
+    live = chunks[:, 3, :] != 0
+    # every entry lands exactly once, in order; padding has flags off
+    for i, arr in enumerate(sched):
+        np.testing.assert_array_equal(chunks[:, i, :][live], arr)
+    for c in chunks:
+        n = int((c[3] != 0).sum())
+        assert c[3, n - 1] & 4, "a chunk must end on a rank's last visit"
+        assert (c[0, n:] == c[0, n - 1]).all()
+    assert len(chunks) >= -(-len(sched[0]) // 16)
+
+
+def test_masked_matmul_rejects_untileable_blocks():
+    """Compiled for the chip, blocks must tile by (8, 128); the check
+    raises before Mosaic does, and interpret mode keeps small blocks."""
+    a = jnp.ones((64, 64), jnp.float32)
+    bi = bj = jnp.zeros(1, jnp.int32)
+    with pytest.raises(ValueError, match="cannot tile"):
+        ops.masked_matmul(a, a, bi, bj, bm=32, bn=32, bk=32,
+                          interpret=False)
+    with pytest.raises(ValueError, match="bn=8"):
+        ops._check_tpu_tiling((256, 256), (256, 256), 128, 8, 128)
+    ops._check_tpu_tiling((256, 256), (256, 256), 128, 128, 128)
+    ops._check_tpu_tiling((8, 32), (32, 64), 8, 64, 32)   # full-dim blocks
+    out = ops.masked_matmul(a, a, bi, bj, bm=32, bn=32, bk=32,
+                            interpret=True)
+    np.testing.assert_allclose(np.asarray(out[0]), 64.0)
+
+
 def test_interpret_false_off_tpu_routes_to_xla():
     """Regression: ``interpret=False`` with the default backend used to be
     read as "pallas, compiled mode" — which crashes off-TPU (Mosaic cannot

@@ -346,3 +346,17 @@ def test_env_var_activates_profile_in_child_process(tmp_path):
                          capture_output=True, text=True, env=env)
     assert out.returncode == 0, out.stderr
     assert out.stdout.startswith("ok env-test-")
+
+
+def test_measure_in_process_never_spawns_a_chip_child(monkeypatch):
+    """Enough devices: measure here.  Too few on the CPU: a forced-host
+    child stands in.  Too few on an accelerator: raise, since a child
+    would need the chip this process holds."""
+    import jax
+    from repro.tuning.probes import measure_in_process
+    have = len(jax.devices())
+    assert measure_in_process(have) is True
+    assert measure_in_process(have + 1) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="devices needed"):
+        measure_in_process(have + 1)

@@ -16,17 +16,14 @@ writes ``results/bench/health_grid.json``:
   (``_pressure_ok``);
 * ``drift`` — a calibrated cost table stays quiet, then the same table
   warped x256 must trip the detector with the matching
-  ``repro.tune --only`` recommendation (``_drift_ok``);
-* ``report`` — ``repro.obs.report`` must render every committed bench
-  grid, console + HTML (``_report_ok``).
+  ``repro.tune --only`` recommendation (``_drift_ok``).
 """
 from __future__ import annotations
 
 import json
-import os
 import urllib.error
 import urllib.request
-from typing import Dict, List
+from typing import Dict
 
 import numpy as np
 
@@ -220,28 +217,9 @@ def run(n: int = 1024, queries: int = 96, iters: int = 61,
             consts.update(originals[name])
         engine.close()
 
-    # ---- trajectory report over the committed grids -----------------------
-    from repro.obs import report as report_mod
-    bench_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             "..", "results", "bench")
-    rep_obj = report_mod.build_report(bench_dir)
-    html = report_mod.render_html(rep_obj)
-    console = report_mod.render_console(rep_obj, max_rows=3)
-    grids: List[str] = sorted(rep_obj["grids"])
-    report_ok = len(grids) >= 8 and "<svg" in html
-    table["report"] = {
-        "grids_rendered": len(grids), "grids": grids,
-        "regressions": rep_obj["regressions"],
-        "html_bytes": len(html), "console_lines": console.count("\n") + 1,
-    }
-    print(f"[health] report {len(grids)} grids "
-          f"({', '.join(grids)}), {len(rep_obj['regressions'])} "
-          f"regression flags, html {len(html)}B", flush=True)
-
     table["_health_ok"] = bool(health_ok)
     table["_pressure_ok"] = bool(pressure_ok)
     table["_drift_ok"] = bool(drift_ok)
-    table["_report_ok"] = bool(report_ok)
     save("health_grid", table)
     return table
 

@@ -357,9 +357,7 @@ def main():
     print("under pressure:", verdict.status, "-", verdict.reasons[0])
 
     # a drift flag names the exact refit (`python -m repro.tune --only
-    # <family>`) and resets itself when the cost table is retuned; the
-    # cross-PR perf trajectory over results/bench/*_grid.json renders
-    # via `python -m repro.obs.report` (--check gates flag regressions)
+    # <family>`) and resets itself when the cost table is retuned
     print("drift:",
           monitor.drift.report().command or "cost model calibrated")
 

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 Array = jax.Array
 
 # --------------------------------------------------------------------------
@@ -88,6 +90,17 @@ def _expand_rows(indptr: np.ndarray) -> np.ndarray:
     """Row index of every nonzero, from indptr."""
     counts = np.diff(indptr)
     return np.repeat(np.arange(len(counts), dtype=np.int64), counts)
+
+
+def to_device(x, dtype=None) -> Array:
+    """``jnp.asarray(x, dtype)``: one host-to-device copy, under an
+    ``spgemm.h2d`` span whose ``bytes`` are the array's size as it lands
+    on the device.  The span times the host's part of the call, which
+    may return before the transfer has finished."""
+    with obs.span("spgemm.h2d") as sp:
+        out = jnp.asarray(x, dtype)
+        sp.set(bytes=out.nbytes)
+    return out
 
 
 def csr_from_coo(rows, cols, vals, shape, sum_dups: bool = True) -> CSR:
@@ -385,8 +398,8 @@ def padded_from_csr(a: CSR, width: Optional[int] = None, dtype=jnp.float32) -> P
     cols[rows[keep], slots[keep]] = a.indices[keep]
     vals[rows[keep], slots[keep]] = a.data[keep]
     return PaddedCSR(
-        jnp.asarray(cols), jnp.asarray(vals, dtype=dtype),
-        jnp.asarray(np.minimum(row_nnz, w), dtype=jnp.int32), (m, n)
+        to_device(cols), to_device(vals, dtype),
+        to_device(np.minimum(row_nnz, w), jnp.int32), (m, n)
     )
 
 
@@ -485,17 +498,20 @@ def bcsr_from_csr(a: CSR, block_size: int, dtype=None) -> BCSR:
     bs = block_size
     m, n = a.shape
     mb, nb = -(-m // bs), -(-n // bs)
-    rows = _expand_rows(a.indptr)
-    cols = a.indices
-    key = (rows // bs) * nb + cols // bs
-    uniq, inv = np.unique(key, return_inverse=True)
-    blocks = np.zeros((len(uniq), bs, bs), dtype=a.data.dtype)
-    blocks[inv, rows % bs, cols % bs] = a.data
-    ubr, ubc = uniq // nb, uniq % nb
-    indptr = np.zeros(mb + 1, dtype=np.int64)
-    np.add.at(indptr, ubr + 1, 1)
-    dev = jnp.asarray(blocks) if dtype is None else jnp.asarray(blocks, dtype)
-    return BCSR(np.cumsum(indptr), ubc.astype(np.int64), dev, (m, n), bs)
+    with obs.span("spgemm.bcsr", bs=bs) as sp:
+        rows = _expand_rows(a.indptr)
+        cols = a.indices
+        key = (rows // bs) * nb + cols // bs
+        uniq, inv = np.unique(key, return_inverse=True)
+        sp.set(nnzb=len(uniq))
+        blocks = np.zeros((len(uniq), bs, bs), dtype=a.data.dtype)
+        blocks[inv, rows % bs, cols % bs] = a.data
+        ubr, ubc = uniq // nb, uniq % nb
+        indptr = np.zeros(mb + 1, dtype=np.int64)
+        np.add.at(indptr, ubr + 1, 1)
+        dev = to_device(blocks, dtype)
+        return BCSR(np.cumsum(indptr), ubc.astype(np.int64), dev, (m, n),
+                    bs)
 
 
 def bcsr_to_csr(a: BCSR, prune_zero: bool = True) -> CSR:
@@ -739,7 +755,8 @@ def block_sparse(n: int, bs: int, tile_density: float,
 
 
 def tril(a: CSR, strict: bool = True) -> CSR:
-    rows = _expand_rows(a.indptr)
-    keep = a.indices < rows if strict else a.indices <= rows
-    return csr_from_coo(rows[keep], a.indices[keep], a.data[keep], a.shape,
-                        sum_dups=False)
+    with obs.span("graph.tril"):
+        rows = _expand_rows(a.indptr)
+        keep = a.indices < rows if strict else a.indices <= rows
+        return csr_from_coo(rows[keep], a.indices[keep], a.data[keep],
+                            a.shape, sum_dups=False)

@@ -29,7 +29,8 @@ from repro import obs
 
 from . import accumulators as acc
 from .formats import (CSR, PaddedCSR, padded_from_csr, csr_from_coo,
-                      bcsr_from_csr, bcsr_block_positions, _expand_rows)
+                      bcsr_from_csr, bcsr_block_positions, _expand_rows,
+                      to_device)
 from .semiring import Semiring, PLUS_TIMES
 
 #: the vmapped row kernels; the BCSR tile route ("tile") dispatches through
@@ -302,18 +303,17 @@ def gather_mask_aligned(M: CSR, Mb_struct, c_blocks, s_blocks, *, n: int,
     """
     m = M.shape[0]
     bs = Mb_struct.block_size
-    M_p = padded_from_csr(M, wm)
-    pm = M_p.width
-    # host-side addressing: every mask element lives in a mask block by
-    # construction
-    mr = _expand_rows(M.indptr)
-    mc = M.indices
-    pos = bcsr_block_positions(Mb_struct, mr // bs, mc // bs)
-    slots = np.arange(M.nnz, dtype=np.int64) - M.indptr[mr]
-    vals, present = _tile_gather(
-        c_blocks, s_blocks, jnp.asarray(pos), jnp.asarray(mr % bs),
-        jnp.asarray(mc % bs), jnp.asarray(mr), jnp.asarray(slots),
-        m=m, pm=pm)
+    with obs.span("spgemm.gather"):
+        M_p = padded_from_csr(M, wm)
+        # host-side addressing: every mask element lives in a mask block
+        # by construction
+        mr = _expand_rows(M.indptr)
+        mc = M.indices
+        pos = bcsr_block_positions(Mb_struct, mr // bs, mc // bs)
+        slots = np.arange(M.nnz, dtype=np.int64) - M.indptr[mr]
+        addr = [to_device(x) for x in (pos, mr % bs, mc % bs, mr, slots)]
+    vals, present = _tile_gather(c_blocks, s_blocks, *addr, m=m,
+                                 pm=M_p.width)
     return MaskedSpGEMMResult(vals, present, M_p.cols, (m, n))
 
 
@@ -344,8 +344,8 @@ def _stack_padded(mats, width: int) -> PaddedCSR:
             cols[i, rows[keep], slots[keep]] = mat.indices[keep]
             vals[i, rows[keep], slots[keep]] = mat.data[keep]
             lens[i] = np.minimum(mat.row_nnz(), width)
-        return PaddedCSR(jnp.asarray(cols), jnp.asarray(vals),
-                         jnp.asarray(lens), (m_rows, n))
+        return PaddedCSR(to_device(cols), to_device(vals),
+                         to_device(lens), (m_rows, n))
     padded = [m if isinstance(m, PaddedCSR) else padded_from_csr(m, width)
               for m in mats]
     return PaddedCSR(

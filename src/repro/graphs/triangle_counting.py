@@ -15,6 +15,7 @@ from typing import Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.formats import CSR, csr_from_coo, tril, _expand_rows
 from repro.core.masked_spgemm import masked_spgemm
 from repro.core.semiring import PLUS_TIMES
@@ -22,13 +23,14 @@ from repro.core.semiring import PLUS_TIMES
 
 def degree_relabel(a: CSR) -> CSR:
     """Relabel vertices in non-increasing degree order (paper: [29])."""
-    deg = a.row_nnz()
-    order = np.argsort(-deg, kind="stable")
-    rank = np.empty_like(order)
-    rank[order] = np.arange(len(order))
-    rows = rank[_expand_rows(a.indptr)]
-    cols = rank[a.indices]
-    return csr_from_coo(rows, cols, a.data, a.shape, sum_dups=False)
+    with obs.span("graph.relabel"):
+        deg = a.row_nnz()
+        order = np.argsort(-deg, kind="stable")
+        rank = np.empty_like(order)
+        rank[order] = np.arange(len(order))
+        rows = rank[_expand_rows(a.indptr)]
+        cols = rank[a.indices]
+        return csr_from_coo(rows, cols, a.data, a.shape, sum_dups=False)
 
 
 def triangle_count(adj: CSR, *, algorithm: str = "auto",

@@ -33,7 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.formats import BCSR
+from repro import obs
+from repro.core.formats import BCSR, to_device
 from .kernel import masked_matmul_kernel, block_spgemm_kernel
 
 Schedule = Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]
@@ -121,7 +122,16 @@ def build_spgemm_schedule(A: BCSR, B: BCSR, M: BCSR) -> Schedule:
     searchsorted over composite (block-col, block-row) keys.  Work and
     memory are O(sum over mask blocks of nnzb(A block-row)) — the same
     asymptotics the per-block Python loop had, minus the interpreter.
+    Runs under an ``spgemm.schedule`` span with the worklist's
+    ``entries``.
     """
+    with obs.span("spgemm.schedule") as sp:
+        schedule = _spgemm_schedule(A, B, M)
+        sp.set(entries=len(schedule[0]))
+    return schedule
+
+
+def _spgemm_schedule(A: BCSR, B: BCSR, M: BCSR) -> Schedule:
     if M.nnzb == 0:
         return _empty_schedule()
 
@@ -416,12 +426,14 @@ def _run_schedule(A: BCSR, B: BCSR, M: BCSR, schedule: Schedule,
     if blocks_b.shape[0] == 0:
         blocks_b = jnp.zeros((1, bs, bs), blocks_b.dtype)
     if backend == "pallas":
-        chunks = chunk_schedule(schedule,
-                                min(SPGEMM_CHUNK, len(schedule[0])))
-        return _block_spgemm_pallas(blocks_a, blocks_b, jnp.asarray(chunks),
+        with obs.span("spgemm.chunk") as sp:
+            chunks = chunk_schedule(schedule,
+                                    min(SPGEMM_CHUNK, len(schedule[0])))
+            sp.set(chunks=len(chunks))
+        return _block_spgemm_pallas(blocks_a, blocks_b, to_device(chunks),
                                     nnzb_out=M.nnzb, bs=bs,
                                     interpret=interpret)
-    rank, pa, pb, flags = (jnp.asarray(x) for x in schedule)
+    rank, pa, pb, flags = (to_device(x) for x in schedule)
     return _block_spgemm_xla(blocks_a, blocks_b, rank, pa, pb, flags,
                              nnzb_out=M.nnzb, bs=bs)
 

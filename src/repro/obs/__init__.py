@@ -23,12 +23,10 @@ Online health intelligence (quickstart §13) rides the same stream::
         ... serve ...
     eng.health()                         # HealthVerdict{ok|degraded|failing}
 
-while ``python -m repro.obs.report`` renders the cross-PR trajectory of
-every committed bench grid.
+While tracing is enabled every span is also a
+``jax.profiler.TraceAnnotation``: under ``jax.profiler.trace`` the
+program's spans sit on the profile's host plane, on the device's clock.
 """
-# NB: .report is deliberately NOT imported here — it is a CLI module
-# (``python -m repro.obs.report``) and pre-importing it from the package
-# __init__ would trip runpy's double-import warning
 from . import drift, export, health, sinks, slo  # noqa: F401
 from .drift import DriftDetector
 from .exposition import parse_prometheus, render_prometheus

@@ -20,6 +20,14 @@ engine computes them from its injectable clock and hands them to
 Span identity is deterministic: trace and span ids come from process
 counters, never the wall clock or an RNG, so two traced replays of one
 recorded stream produce identically-numbered spans.
+
+One clock with the device: while tracing is enabled, every span also
+opens a ``jax.profiler.TraceAnnotation`` of its own name for its whole
+duration, on its own thread.  Under ``jax.profiler.trace`` the span then
+sits on the profile's ``/host:CPU`` plane, on the same clock as the
+device planes, so an idle gap on the device can be named by the span
+the host was in.  ``event`` and ``counter`` records are measured
+elsewhere or have no duration, and are not mirrored.
 """
 from __future__ import annotations
 
@@ -73,7 +81,7 @@ class Span:
     """
 
     __slots__ = ("_tracer", "name", "span_id", "trace", "attrs",
-                 "parent", "_t0", "_tok_parent", "_tok_trace")
+                 "parent", "_t0", "_tok_parent", "_tok_trace", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str,
                  trace: Optional[int], attrs: Dict):
@@ -96,11 +104,15 @@ class Span:
             self.trace = _trace_var.get()
         else:
             self._tok_trace = _trace_var.set(self.trace)
+        from jax.profiler import TraceAnnotation
+        self._mirror = TraceAnnotation(self.name)
+        self._mirror.__enter__()
         self._t0 = time.perf_counter()  # lint: clock-ok(span start stamp)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter() - self._t0  # lint: clock-ok(span duration)
+        self._mirror.__exit__(exc_type, exc, tb)
         if self._tok_parent is not None:
             _parent_var.reset(self._tok_parent)
         if self._tok_trace is not None:
@@ -122,7 +134,8 @@ class Tracer:
 
     Ids are drawn from process-wide counters (deterministic across
     replays of one stream); emission is serialized by the sink itself
-    (both shipped sinks lock internally).
+    (both shipped sinks lock internally).  Every span it opens is
+    mirrored as a ``jax.profiler.TraceAnnotation`` (module docstring).
     """
 
     def __init__(self, sink):

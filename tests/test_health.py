@@ -1,4 +1,4 @@
-"""Online health intelligence (PR 10): windows, SLOs, drift, report.
+"""Online health intelligence: windows, SLOs, drift.
 
 The contracts under test:
 
@@ -14,9 +14,7 @@ The contracts under test:
   band with a concrete ``repro.tune --only`` recommendation, resets on
   a cost-model-token change, and skips burst-route spans;
 * ``engine.health()`` + ``/health`` (503-with-reasons when failing) +
-  ``/metrics`` ``repro_slo_*``/``repro_drift_*`` surface all of it;
-* ``python -m repro.obs.report`` loads committed grid generations from
-  git history and machine-flags acceptance-flag regressions.
+  ``/metrics`` ``repro_slo_*``/``repro_drift_*`` surface all of it.
 """
 import json
 import math
@@ -30,7 +28,6 @@ import pytest
 from repro import caches, obs
 from repro.core.formats import erdos_renyi, er_mask
 from repro.core import planner
-from repro.obs import report as report_mod
 from repro.obs.drift import DriftDetector, family_of
 from repro.obs.health import (HealthMonitor, HealthVerdict,
                               WindowAggregator, basic_verdict)
@@ -471,129 +468,3 @@ def test_render_prometheus_without_monitor_has_no_slo_families():
     with QueryEngine() as eng:
         text = obs.render_prometheus(eng)
     assert "repro_slo_" not in text and "repro_drift_" not in text
-
-
-# ---------------------------------------------------------------------------
-# trajectory report (python -m repro.obs.report)
-# ---------------------------------------------------------------------------
-
-
-def _git(args, cwd):
-    return subprocess.run(["git", *args], cwd=str(cwd),
-                          capture_output=True, text=True)
-
-
-@pytest.fixture
-def grid_repo(tmp_path):
-    repo = tmp_path / "repo"
-    bench = repo / "results" / "bench"
-    bench.mkdir(parents=True)
-    assert _git(["init", "-q"], repo).returncode == 0
-    _git(["config", "user.email", "t@example.com"], repo)
-    _git(["config", "user.name", "t"], repo)
-
-    def commit(payload, msg="gen"):
-        text = (payload if isinstance(payload, str)
-                else json.dumps(payload))
-        (bench / "unit_grid.json").write_text(text)
-        _git(["add", "-A"], repo)
-        assert _git(["commit", "-qm", msg], repo).returncode == 0
-
-    return repo, bench, commit
-
-
-def test_report_tracks_generations_and_trends(grid_repo, tmp_path):
-    repo, bench, commit = grid_repo
-    commit({"perf": {"qps": 100.0}, "_ok": True}, "gen1")
-    commit({"perf": {"qps": 150.0}, "_ok": True}, "gen2")
-    rep = report_mod.build_report(str(bench))
-    gens = rep["grids"]["unit"]
-    assert len(gens) == 2 and all(g.readable for g in gens)
-    assert rep["regressions"] == []
-    rows = dict(report_mod._trend_rows(gens))
-    assert rows["perf.qps"] == [100.0, 150.0]
-    console = report_mod.render_console(rep)
-    assert "unit" in console and "_ok: PASS" in console
-    assert "no regressions" in console
-    html_path = tmp_path / "report.html"
-    rc = report_mod.main(["--dir", str(bench), "--check",
-                          "--html", str(html_path)])
-    assert rc == 0
-    html = html_path.read_text()
-    assert "<svg" in html and "perf.qps" in html
-
-
-def test_report_flags_true_to_false_regression(grid_repo):
-    repo, bench, commit = grid_repo
-    commit({"qps": 100.0, "_ok": True}, "good")
-    commit({"qps": 90.0, "_ok": False}, "bad")
-    rep = report_mod.build_report(str(bench))
-    assert len(rep["regressions"]) == 1
-    assert "_ok regressed True->False" in rep["regressions"][0]
-    assert report_mod.main(["--dir", str(bench), "--check"]) == 1
-    # a flag that was never True is not a regression (new gate landing red
-    # is its own PR's problem, not a trajectory regression)
-    commit({"qps": 80.0, "_ok": False, "_new": False}, "still-bad")
-    rep = report_mod.build_report(str(bench))
-    assert rep["regressions"] == []
-
-
-def test_report_flags_unreadable_newest_generation(grid_repo):
-    repo, bench, commit = grid_repo
-    commit({"qps": 1.0, "_ok": True}, "good")
-    commit("{not json", "broken")
-    rep = report_mod.build_report(str(bench))
-    assert any("unreadable" in r for r in rep["regressions"])
-    assert report_mod.main(["--dir", str(bench), "--check"]) == 1
-    # non-flag schema: _ok must be a bool
-    commit({"qps": 1.0, "_ok": "yes"}, "bad-schema")
-    rep = report_mod.build_report(str(bench))
-    assert any("must be a bool" in r for r in rep["regressions"])
-
-
-def test_report_includes_dirty_worktree_as_generation(grid_repo):
-    repo, bench, commit = grid_repo
-    commit({"qps": 1.0}, "gen1")
-    (bench / "unit_grid.json").write_text(json.dumps({"qps": 2.0}))
-    gens = report_mod.generations(str(bench / "unit_grid.json"))
-    assert [g.label for g in gens][-1] == "worktree"
-    assert len(gens) == 2
-    # clean worktree: no duplicate generation
-    _git(["add", "-A"], repo)
-    _git(["commit", "-qm", "gen2"], repo)
-    gens = report_mod.generations(str(bench / "unit_grid.json"))
-    assert len(gens) == 2 and gens[-1].label != "worktree"
-
-
-def test_report_outside_git_uses_disk_only(tmp_path):
-    bench = tmp_path  # tmp under pytest is not itself a grid-bearing repo
-    (bench / "solo_grid.json").write_text(json.dumps({"x": 1.0}))
-    gens = report_mod.generations(str(bench / "solo_grid.json"))
-    assert [g.label for g in gens] == ["worktree"] or len(gens) >= 1
-    assert gens[-1].readable
-
-
-def test_report_renders_all_committed_grids():
-    import os
-    bench = os.path.join(os.path.dirname(__file__), "..", "results",
-                         "bench")
-    rep = report_mod.build_report(bench)
-    assert len(rep["grids"]) >= 8          # every committed *_grid.json
-    out = report_mod.render_console(rep, max_rows=2)
-    assert "obs_overhead" in out
-    report_mod.render_html(rep)            # must not raise
-
-
-def test_sparkline_and_formatting_helpers():
-    assert report_mod.sparkline([]) == ""
-    assert report_mod.sparkline([1.0, 1.0]) == "▄▄"
-    s = report_mod.sparkline([0.0, 0.5, 1.0])
-    assert s[0] == "▁" and s[-1] == "█"
-    assert " " in report_mod.sparkline([0.0, float("nan"), 1.0])
-    assert report_mod._delta([1.0, 2.0]) == "+100.0%"
-    assert report_mod._delta([5.0]) == ""
-    assert report_mod.flatten_metrics(
-        {"a": {"b": 2}, "_flag": True, "_cache_info": {"x": {"y": 1}},
-         "s": "str"}) == {"a.b": 2.0}
-    assert report_mod.grid_flags({"_ok": True, "_bad": False,
-                                  "n": 1}) == {"_bad": False, "_ok": True}

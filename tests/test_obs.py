@@ -553,3 +553,161 @@ def test_residual_record_filters_and_normalizes():
     assert residual_record(bad) is None
     zero = {"name": "serve.exec", "dur": 1.0, "attrs": {"modeled_ms": 0.0}}
     assert residual_record(zero) is None
+
+
+# ---------------------------------------------------------------------------
+# host-prep spans and the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def _lower(n=96, seed=5):
+    from repro.core.formats import tril
+    return tril(erdos_renyi(n, 8, seed=seed, values="ones"))
+
+
+def _tile_solve(L, backend="pallas"):
+    from repro.core.masked_spgemm import _masked_spgemm_tile
+    return _masked_spgemm_tile(L, L, L, block_size=8, backend=backend,
+                               interpret=True if backend == "pallas"
+                               else None)
+
+
+def test_tile_route_spans_name_its_host_work():
+    """One schedule build, one chunking per replay (values, structure),
+    five BCSR conversions (three operands, two patterns); host_prep still
+    holds exactly the BCSR conversions, and the rest sits beside it."""
+    from repro.core.formats import bcsr_from_csr
+    from repro.kernels.masked_matmul import ops
+    L = _lower()
+    with obs.tracing() as tr:
+        _tile_solve(L)
+    recs = tr.sink.spans()
+    names = [r["name"] for r in recs]
+    assert (names.count("spgemm.schedule"), names.count("spgemm.chunk"),
+            names.count("spgemm.bcsr"), names.count("spgemm.gather")) \
+        == (1, 2, 5, 1)
+
+    def children(name):
+        (parent,) = [r for r in recs if r["name"] == name]
+        return sorted(r["name"] for r in recs
+                      if r["parent"] == parent["span"])
+
+    assert children("spgemm.host_prep") == ["spgemm.bcsr"] * 5
+    assert children("spgemm.tile") == sorted(
+        ["spgemm.host_prep", "spgemm.schedule", "spgemm.chunk",
+         "spgemm.chunk", "spgemm.h2d", "spgemm.h2d", "spgemm.gather"])
+    Lb = bcsr_from_csr(L, 8)
+    entries = len(ops.build_spgemm_schedule(Lb, Lb, Lb)[0])
+    attrs = {r["name"]: r.get("attrs") for r in recs}
+    assert attrs["spgemm.schedule"] == {"entries": entries}
+    assert attrs["spgemm.bcsr"] == {"bs": 8, "nnzb": Lb.nnzb}
+    assert attrs["spgemm.chunk"] == {"chunks": 1}
+
+
+def _h2d_case(route, L):
+    """A solve on ``route``, and the bytes its device operands take,
+    computed from their shapes (f32 values, int32 ids and lengths)."""
+    from repro.core.formats import bcsr_from_csr
+    from repro.core.masked_spgemm import (masked_spgemm,
+                                          masked_spgemm_batched)
+    from repro.kernels.masked_matmul import ops
+    m = L.nrows
+    widest = int(np.diff(L.indptr).max())
+    widest_col = int(np.bincount(L.indices, minlength=m).max())
+    padded = lambda w: m * w * 8 + m * 4  # noqa: E731  cols, vals, lens
+    if route == "tile":
+        Lb = bcsr_from_csr(L, 8)
+        chunk_len = min(ops.SPGEMM_CHUNK,
+                        len(ops.build_spgemm_schedule(Lb, Lb, Lb)[0]))
+        return (lambda: _tile_solve(L),
+                5 * Lb.nnzb * 8 * 8 * 4        # three operands, two patterns
+                + 2 * 4 * chunk_len * 4        # one chunk per replay
+                + padded(widest)               # the mask, padded
+                + 5 * L.nnz * 4)               # gather addressing
+    if route == "row":
+        return (lambda: masked_spgemm(L, L, L, algorithm="inner"),
+                2 * padded(widest) + padded(widest_col))    # A, M; B^T
+    return (lambda: masked_spgemm_batched([L, L], L, [L, L],
+                                          algorithm="msa"),
+            2 * (2 * padded(widest)) + padded(widest))      # A, M; B
+
+
+@pytest.mark.parametrize("route", ["tile", "row", "batched"])
+def test_h2d_bytes_are_the_device_operands(route):
+    solve, want = _h2d_case(route, _lower())
+    with obs.tracing() as tr:
+        solve()
+    h2d = [r for r in tr.sink.spans() if r["name"] == "spgemm.h2d"]
+    assert h2d and sum(r["attrs"]["bytes"] for r in h2d) == want
+
+
+@pytest.mark.parametrize("name", ["graph.relabel", "graph.tril"])
+def test_graph_prep_spans(name):
+    from repro.core.formats import tril
+    from repro.graphs.triangle_counting import degree_relabel
+    adj = erdos_renyi(64, 4, seed=2, values="ones")
+    with obs.tracing() as tr:
+        tril(degree_relabel(adj))
+    recs = tr.sink.spans()
+    assert [r["name"] for r in recs].count(name) == 1
+    (r,) = [r for r in recs if r["name"] == name]
+    assert r["parent"] is None and r["dur"] > 0
+
+
+def _host_plane_events(tmp_path, body):
+    """Events of the profile's host plane while ``body`` runs under
+    ``jax.profiler`` (python tracer off, as the benchmark traces)."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    return [(ev.name, ev.start_ns, ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events]
+
+
+def test_spans_sit_on_the_profilers_host_plane(tmp_path):
+    """Every span record appears once on the profile's host plane, under
+    its own name, lasting as long as the record says; events and counters
+    are not mirrored."""
+    L = _lower()
+    recs = []
+
+    def body():
+        with obs.tracing() as tr:
+            _tile_solve(L, backend="xla")
+            obs.event("mirror.event", dur_s=0.5)
+            obs.counter("mirror.counter", 1.0)
+        recs.extend(tr.sink.spans())
+
+    events = _host_plane_events(tmp_path, body)
+    spans = [r for r in recs if r["name"].startswith("spgemm.")]
+    assert {"spgemm.tile", "spgemm.schedule", "spgemm.h2d",
+            "spgemm.gather"} <= {r["name"] for r in spans}
+    for name in {r["name"] for r in spans}:
+        mine = sorted((r["t0"], r["dur"]) for r in spans
+                      if r["name"] == name)
+        seen = sorted((s, d) for n, s, d in events if n == name)
+        assert len(seen) == len(mine), name
+        for (_, dur), (_, ns) in zip(mine, seen):
+            assert ns / 1e9 == pytest.approx(dur, rel=0.05, abs=2e-3)
+    names = {n for n, _, _ in events}
+    assert "mirror.event" not in names and "mirror.counter" not in names
+
+
+def test_tracing_off_mirrors_nothing(tmp_path):
+    """Off, a span site still hands back the shared no-op span, and a solve
+    under the profiler leaves none of the program's span names there."""
+    L = _lower()
+    assert obs.span("spgemm.h2d") is _NULL_SPAN
+    events = _host_plane_events(tmp_path, lambda: _tile_solve(L, "xla"))
+    names = {n for n, _, _ in events}
+    assert not {n for n in names if n.startswith(("spgemm.", "graph."))}

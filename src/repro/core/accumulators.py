@@ -16,6 +16,10 @@ Vectorization notes (faithfulness vs. the CPU paper):
     log-factor instead of linear scan); the Heap's multiway merge is realized
     as sort + segmented reduction, the standard data-parallel equivalent of a
     priority-queue merge.
+  * Inner intersects its two index lists by comparing every pair of padded
+    slots (``wa * wbt`` compares, no search, no element gather): on a TPU
+    each step of a ``searchsorted`` and each gather after it is a scalar
+    gather, far slower than a vector compare.
   * INSERT's lambda deferral ("only evaluate the product if it will not be
     discarded") becomes predication: products are computed vector-wide and
     masked, which on SIMD hardware is the same optimization.
@@ -300,24 +304,42 @@ def heap_row(m_cols, a_cols, a_vals, a_len, B_cols, B_vals, B_lens,
 # ---------------------------------------------------------------------------
 
 
+def _intersect(a_cols, a_ok, bcols, bbits):
+    """Each A slot's matching B value, as its bits, and whether it matched,
+    by comparing every (A slot, B slot) pair: ``wa * wbt`` vector compares,
+    no search and no element gather.  Column ids within a row are unique,
+    so an A slot matches at most one B slot, and OR-ing the bits over the
+    B slots returns the matched value's bit for bit.  ``bcols`` holds an
+    out-of-range id in every invalid slot, which no valid A slot equals."""
+    hit = (a_cols[:, None] == bcols[None, :]) & a_ok[:, None]
+    bits = jnp.where(hit, bbits[None, :], jnp.zeros((), bbits.dtype))
+    bits = jax.lax.reduce(bits, jnp.zeros((), bbits.dtype), jax.lax.bitwise_or,
+                          (1,))
+    return bits, jnp.any(hit, 1)
+
+
 def inner_row(m_cols, a_cols, a_vals, a_len,
               Bt_cols, Bt_vals, Bt_lens, n: int, kdim: int, sr: Semiring):
     """Pull algorithm: for each mask nonzero j, sparse dot  A_i* . B_*j.
 
     ``Bt_*`` is B stored column-major (CSC == CSR of B^T), as the paper
-    prescribes.  Intersection of the two sorted index lists via searchsorted.
+    prescribes.  The two sorted index lists are intersected by an equality
+    compare over their padded slots (``_intersect``).
     """
     wa = a_cols.shape[0]
-    a_valid = jnp.arange(wa) < a_len
+    a_ok = (jnp.arange(wa) < a_len) & (a_cols < kdim)
+    # B^T's columns with kdim in every slot past the row's length, and its
+    # value bits, built once (neither depends on the row): a mask slot then
+    # gathers two whole rows and no scalar length
+    bt_cols = jnp.where(jnp.arange(Bt_cols.shape[1]) < Bt_lens[:, None],
+                        Bt_cols, kdim)
+    bits_t = jnp.dtype(f"uint{8 * Bt_vals.dtype.itemsize}")
+    bt_bits = jax.lax.bitcast_convert_type(Bt_vals, bits_t)
 
     def one_dot(j):
-        bcols, bvals, bvalid = _b_row(Bt_cols, Bt_vals, Bt_lens, j, n)
-        # locate each A-row index inside B's column-j index list
-        idx = jnp.minimum(jnp.searchsorted(bcols, a_cols), bcols.shape[0] - 1)
-        hit = (bcols[idx] == a_cols) & a_valid & (a_cols < kdim)
-        hit = hit & bvalid[idx]
-        prod = sr.mul(a_vals, bvals[idx])
-        contrib = jnp.where(hit, prod, sr.zero)
+        bits, hit = _intersect(a_cols, a_ok, bt_cols[j], bt_bits[j])
+        matched = jax.lax.bitcast_convert_type(bits, Bt_vals.dtype)
+        contrib = jnp.where(hit, sr.mul(a_vals, matched), sr.zero)
         # semiring-reduce the intersection
         red = jax.lax.reduce(contrib, jnp.asarray(sr.zero, contrib.dtype),
                              sr.add, (0,))
@@ -509,8 +531,9 @@ def _heap_workspace(*, n, wa, wb, wbt, pm):
 
 
 def _inner_workspace(*, n, wa, wb, wbt, pm):
-    # per mask slot: the gathered B^T row and each A entry's search position
-    return pm * (9.0 * wbt + 4.0 * wa)
+    # per mask slot: the gathered B^T row, held as columns, value bits and
+    # one relayout copy (12 bytes a slot; the compare fuses into its reduce)
+    return pm * (13.0 * wbt + 4.0 * wa)
 
 
 #: algorithm name -> device bytes per vmapped row

@@ -204,7 +204,8 @@ def masked_spgemm(A, B, M, *, algorithm: str = "auto",
         counts = symbolic_phase(A_p, M_p, B_sym, shape=(m, n), kdim=k)
         _ = counts.block_until_ready()
 
-    with obs.span("spgemm.row", algorithm=algorithm, m=m, n=n):
+    attrs = {"intersect": "compare_all"} if algorithm == "inner" else {}
+    with obs.span("spgemm.row", algorithm=algorithm, m=m, n=n, **attrs):
         vals, present = _masked_spgemm_padded(
             M_p, A_p, B_p, algorithm=algorithm, sr=semiring,
             complement=complement, n_inspect=n_inspect, shape=(m, n),

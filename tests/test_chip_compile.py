@@ -136,3 +136,44 @@ def test_scale16_row_program_fits_one_chip(one_chip):
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
+
+
+def _inner_program(one_chip, m, n, wa, wbt, pm):
+    from repro.core.formats import PaddedCSR
+    from repro.core.masked_spgemm import _masked_spgemm_padded
+    from repro.core.semiring import PLUS_TIMES
+
+    def padded(rows, width):
+        return PaddedCSR(
+            jax.ShapeDtypeStruct((rows, width), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((rows, width), jnp.float32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
+            (rows, n))
+
+    return _masked_spgemm_padded.lower(
+        padded(m, pm), padded(m, wa), padded(n, wbt), algorithm="inner",
+        sr=PLUS_TIMES, complement=False, n_inspect=None, shape=(m, n),
+        kdim=n).compile()
+
+
+def test_scale16_inner_program_fits_one_chip(one_chip):
+    """The row program the uniform scale-16 triangle count elects (inner at
+    widths 29, 58, 29) compiles at m = 65,536 with the compare
+    intersection: no loop, and fewer temporaries than the searchsorted
+    program's 5.87 GB."""
+    compiled = _inner_program(one_chip, 65536, 65536, 29, 58, 29)
+    assert " while(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.87e9
+
+
+def test_inner_workspace_bounds_the_wide_program(one_chip):
+    """Where B^T's rows are far wider than A's (R-MAT scale 12: widths 75,
+    1318, 75), the compare fuses into its reduce, and the planner's memory
+    filter (``ROW_WORKSPACE["inner"]``) bounds the program's temporaries."""
+    from repro.core import accumulators as acc
+    m, wa, wbt, pm = 4096, 75, 1318, 75
+    compiled = _inner_program(one_chip, m, m, wa, wbt, pm)
+    modeled = m * acc.ROW_WORKSPACE["inner"](n=m, wa=wa, wb=wa, wbt=wbt,
+                                             pm=pm)
+    assert compiled.memory_analysis().temp_size_in_bytes <= modeled

@@ -93,8 +93,9 @@ def test_memory_limit_drops_kernels_that_cannot_fit():
 @pytest.mark.parametrize("scale,inner_fits", [(12, True), (13, False)])
 def test_memory_limit_on_inner_follows_the_chip_compiler(scale, inner_fits):
     """Compiled for a v5e, inner's program for the R-MAT triangle count
-    takes 4.0 GB at scale 12 and 17.0 GB at scale 13, against the chip's
-    16.9 GB ``bytes_limit``: the planner keeps it only where it fits."""
+    takes 5.2 GB at scale 12 and needs 21.3 GB at scale 13, against the
+    chip's 16.9 GB ``bytes_limit``: the planner keeps it only where it
+    fits."""
     from repro.core.formats import tril
     from repro.graphs.triangle_counting import degree_relabel
     L = tril(degree_relabel(rmat(scale, 16, seed=1)), strict=True)

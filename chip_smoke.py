@@ -282,7 +282,7 @@ def phase_four_chips(big, small) -> None:
         log(f"[single] device 0, {route} comparison: triangles="
             f"{single_count} ({time.perf_counter() - t0:.1f}s)")
         check(single_count == ref, f"single chip counted {single_count}")
-        # host copies only: the ring program needs most of device 0's HBM
+        # host copies: the comparison reads them after the mesh route ran
         want_present = np.asarray(single.present)
         want_vals = np.asarray(single.vals)
         del single

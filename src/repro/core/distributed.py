@@ -9,14 +9,15 @@ naturally across a mesh:
   for nnz(B) small vs aggregate memory — typical graph masks.
 
 * ``ring_sparse_masked_spgemm`` — 1.5D sparse ring-SUMMA on BCSR operands
-  when B is too large to replicate: A/M row-block-panels are sharded, B's
-  *occupied* BCSR K-slabs rotate around the ring via ``jax.lax.ppermute``
-  (each panel = ``(nnzb_slab, bs, bs)`` value+pattern blocks, padded to the
-  ring-wide max so every rotation has one static shape).  Each stage
-  replays a host-built K-slab worklist on the block executors (Pallas on
-  TPU, chunked XLA elsewhere) — no dense ``(k, n)`` or ``(m, n)`` array
-  exists anywhere on this path, which is what makes it usable at scales
-  where ``ring_masked_matmul``'s dense operands would not fit.
+  when B is too large to replicate: block rows are dealt to the devices
+  round robin, A/M's as row panels and B's as *occupied* BCSR K-slabs
+  that rotate around the ring via ``jax.lax.ppermute`` (value+pattern
+  blocks, padded to the ring-wide max so every rotation has one static
+  shape).  Each stage replays a host-built worklist on the block
+  executors (Pallas on TPU, chunked XLA elsewhere) — no dense ``(k, n)``
+  or ``(m, n)`` array exists anywhere on this path, which is what makes
+  it usable at scales where ``ring_masked_matmul``'s dense operands
+  would not fit.
 
 * ``ring_masked_matmul`` — the dense 1.5D ring (tile-granular skipping),
   kept for dense-operand workloads and as the bench baseline the sparse
@@ -43,12 +44,15 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import caches
 from repro import obs
 
-from .formats import CSR, PaddedCSR, bcsr_row_panels, padded_from_csr
+from repro.kernels.masked_matmul.kernel import (block_layout, block_position,
+                                                pack_blocks, unpack_blocks)
+
+from .formats import BCSR, CSR, PaddedCSR, padded_from_csr, to_device
 from .masked_spgemm import MaskedSpGEMMResult, _row_fn
 from .semiring import Semiring, PLUS_TIMES
 
@@ -211,6 +215,7 @@ def _ring_stage_xla(out, a_blocks, b_blocks, chunks, *, bs):
     pieces like ``ops._block_spgemm_xla`` so peak memory stays
     O(piece * bs^2)."""
     from repro.kernels.masked_matmul.ops import _XLA_CHUNK_ELEMS
+    rows = out.shape[1]
     rank, pa, pb, flags = (chunks[:, i, :].reshape(-1) for i in range(4))
     ws = int(rank.shape[0])
     piece = max(1, _XLA_CHUNK_ELEMS // (bs * bs))
@@ -218,131 +223,171 @@ def _ring_stage_xla(out, a_blocks, b_blocks, chunks, *, bs):
         e = min(ws, s0 + piece)
         real = ((flags[s0:e] >> 1) & 1).astype(jnp.float32)
         prods = jnp.einsum("wij,wjk->wik",
-                           a_blocks[pa[s0:e]].astype(jnp.float32),
-                           b_blocks[pb[s0:e]].astype(jnp.float32),
+                           unpack_blocks(a_blocks[pa[s0:e]], bs),
+                           unpack_blocks(b_blocks[pb[s0:e]], bs),
                            preferred_element_type=jnp.float32)
-        out = out.at[rank[s0:e]].add(prods * real[:, None, None])
+        out = out.at[rank[s0:e]].add(
+            pack_blocks(prods * real[:, None, None], rows))
     return out
 
 
 def _ring_stage_pallas(out, a_blocks, b_blocks, chunks, *, bs, interpret):
-    """One ring stage on the Pallas executor: the worklist covers every
-    output rank (zero-fill + padding-rank entries from
-    ``build_ring_schedules``), so the replay's output is fully defined and
-    adds into the running accumulator."""
+    """One ring stage on the Pallas executor: the replay adds each visited
+    rank's products into the running accumulator in place
+    (``accumulate``); ranks the stage does not visit keep their value."""
     from repro.kernels.masked_matmul.ops import replay_chunks
-    stage = replay_chunks(jnp.zeros_like(out), a_blocks, b_blocks, chunks,
-                          bs=bs, interpret=interpret)
-    return out + stage
+    return replay_chunks(out, a_blocks, b_blocks, chunks, bs=bs,
+                         interpret=interpret, accumulate=True)
 
 
 @functools.lru_cache(maxsize=64)
-def _ring_sparse_program(mesh: Mesh, axis: str, p: int, bs: int,
-                         wm_blocks: int, pm: int, rows_loc: int,
+def _ring_sparse_program(mesh: Mesh, axis: str, p: int, bs: int, wa: int,
+                         wb: int, wm: int, pm: int, rows_loc: int,
                          backend: str, interpret: Optional[bool]):
     """Compiled sparse-ring program (cached: see _row_parallel_program).
-    Panel/worklist lengths vary per problem and are handled by the jit
-    cache; only the quantities baked into the trace are keys here.
+    Worklist and entry-list lengths vary per problem and are handled by
+    the jit cache; only the quantities baked into the trace are keys here.
+
+    Each device builds its blocks from the entries it is sent (values and
+    their block coordinates, ``_ring_prep``): its row panel of A (values
+    and stored-entry pattern, ``wa`` blocks) and its B K-slab (``wb``).
+    The slab rotates around the ring while each stage's products add in
+    place into the panel's value and count accumulators (``wm`` blocks).
+    Blocks are stored lane-dense (``block_layout``), so a device holds
+    eight arrays of ``wa``, ``wb`` or ``wm`` blocks of ``bs * bs`` words:
+    A's two, B's two and the two in flight, and the accumulators.
 
     The mask-aligned extraction runs inside the shard program: every mask
-    element lives in exactly one row-panel, so each device scatters its own
-    elements into its ``(rows_loc, pm)`` output shard — no cross-device
-    gather of block panels ever happens.
+    element lives in exactly one row panel, so each device scatters its
+    own elements into its ``(rows_loc, pm)`` output shard — no
+    cross-device gather of block panels ever happens.
     """
+    rows, lanes = block_layout(bs)
     if backend == "xla":
         apply_stage = functools.partial(_ring_stage_xla, bs=bs)
     else:
         apply_stage = functools.partial(_ring_stage_pallas, bs=bs,
                                         interpret=interpret)
 
-    def local(av, ap, bv, bp, sc, loc, roff, coff, rowl, slot):
-        av, ap, bv, bp, sc = av[0], ap[0], bv[0], bp[0], sc[0]
-        loc, roff, coff, rowl, slot = (x[0] for x in
-                                       (loc, roff, coff, rowl, slot))
-        panel = jnp.stack([bv, bp])        # values+pattern rotate together
+    def blocks(n, at, values):
+        return jnp.zeros((n, rows, lanes), jnp.float32).at[
+            at[0], at[1], at[2]].set(values, mode="drop")
 
-        def compute(s, vals, cnts, pan):
-            chunks = jax.lax.dynamic_index_in_dim(sc, s, 0, keepdims=False)
-            vals = apply_stage(vals, av, pan[0], chunks)
-            cnts = apply_stage(cnts, ap, pan[1], chunks)
-            return vals, cnts
+    def local(a_at, a_vals, b_at, b_vals, sched, ex):
+        a_at, a_vals, b_at, b_vals, sched, ex = (
+            x[0] for x in (a_at, a_vals, b_at, b_vals, sched, ex))
+        av, ap = blocks(wa, a_at, a_vals), blocks(wa, a_at, 1.0)
+        bv, bp = blocks(wb, b_at, b_vals), blocks(wb, b_at, 1.0)
+        ring = [(i, (i + 1) % p) for i in range(p)]
+
+        def compute(s, vals, cnts, bv, bp):
+            chunks = jax.lax.dynamic_index_in_dim(sched, s, 0, keepdims=False)
+            return (apply_stage(vals, av, bv, chunks),
+                    apply_stage(cnts, ap, bp, chunks))
 
         def stage(s, carry):
-            vals, cnts, pan = carry
-            # prefetch the next panel first -> XLA overlaps the collective
-            # with this stage's block products
-            nxt = jax.lax.ppermute(
-                pan, axis, [(i, (i + 1) % p) for i in range(p)])
-            vals, cnts = compute(s, vals, cnts, pan)
-            return vals, cnts, nxt
+            vals, cnts, bv, bp = carry
+            # send the slab on first -> XLA overlaps the collective with
+            # this stage's block products
+            nxt = tuple(jax.lax.ppermute(x, axis, ring) for x in (bv, bp))
+            return compute(s, vals, cnts, bv, bp) + nxt
 
-        vals = jnp.zeros((wm_blocks, bs, bs), jnp.float32)
-        cnts = jnp.zeros((wm_blocks, bs, bs), jnp.float32)
-        # the last stage is peeled: its prefetched panel would be dropped,
-        # so only p-1 panel rotations are ever transmitted
-        vals, cnts, panel = jax.lax.fori_loop(0, p - 1, stage,
-                                              (vals, cnts, panel))
-        vals, cnts = compute(p - 1, vals, cnts, panel)
+        vals = jnp.zeros((wm, rows, lanes), jnp.float32)
+        cnts = jnp.zeros((wm, rows, lanes), jnp.float32)
+        # the last stage is peeled: its prefetched slab would be dropped,
+        # so only p-1 slab rotations are ever transmitted
+        vals, cnts, bv, bp = jax.lax.fori_loop(0, p - 1, stage,
+                                               (vals, cnts, bv, bp))
+        vals, cnts = compute(p - 1, vals, cnts, bv, bp)
         # panel-local extraction (padding entries carry rowl == rows_loc,
         # dropped by the out-of-bounds scatter mode)
-        out_v = jnp.zeros((rows_loc, pm), jnp.float32)
-        out_p = jnp.zeros((rows_loc, pm), bool)
-        out_v = out_v.at[rowl, slot].set(vals[loc, roff, coff], mode="drop")
-        out_p = out_p.at[rowl, slot].set(cnts[loc, roff, coff] > 0,
-                                         mode="drop")
-        # row-sharded over the axis: global result is (p * rows_loc, pm)
+        loc, r, c, rowl, slot = (ex[i] for i in range(5))
+        out_v = jnp.zeros((rows_loc, pm), jnp.float32).at[rowl, slot].set(
+            vals[loc, r, c], mode="drop")
+        out_p = jnp.zeros((rows_loc, pm), bool).at[rowl, slot].set(
+            cnts[loc, r, c] > 0, mode="drop")
+        # row-sharded over the axis: (p * rows_loc, pm), panel by panel
         return out_v, out_p
 
     spec = P(axis)
     return jax.jit(jax.shard_map(
         local, mesh=mesh,
-        in_specs=(spec,) * 10,
+        in_specs=(spec,) * 6,
         out_specs=(spec, spec), check_vma=False))
 
 
-def _panel_scatter(x: CSR, bs: int, p: int) -> Tuple[np.ndarray, ...]:
-    """Per-entry scatter coordinates into a (p, W, bs, bs) stacked panel
-    array plus the panel block structure.
+@jax.jit
+def _unpanel(vals, present, row_at):
+    """The ring's panel-by-panel rows back in the mask's row order."""
+    return vals[row_at], present[row_at]
 
-    Returns ``(indptr_pad, indices, panel, local, r, c, w)``: entry e of
-    ``x`` lands in ``stacked[panel[e], local[e], r[e], c[e]]``; ``w`` is
-    the max panel nnzb (the ring-wide pad).  Pure structure — values are
-    scattered per call.
-    """
+
+def ring_owner(block_rows: int, p: int) -> np.ndarray:
+    """The device that holds each block row on the sparse ring: dealt
+    round robin.  Degree-ordered graphs put their hubs first and their
+    isolated vertices last, so contiguous panels of equal rows give the
+    first device most blocks and products and the last none; neighbouring
+    block rows carry similar loads, so dealing them out evens the blocks,
+    the products per device and the products of every (panel, slab)
+    stage together."""
+    return np.arange(block_rows, dtype=np.int64) % p
+
+
+def _block_structure(x: CSR, bs: int) -> Tuple[BCSR, np.ndarray, np.ndarray]:
+    """``x``'s block structure (a BCSR without blocks), each entry's row
+    and each entry's block (CSR order)."""
     m, n = x.shape
     nb = -(-n // bs)
-    mb = -(-m // bs)
-    mb_pad = -(-mb // p) * p
     rows = np.repeat(np.arange(m, dtype=np.int64), np.diff(x.indptr))
-    key = (rows // bs) * nb + x.indices // bs
-    uniq, inv = np.unique(key, return_inverse=True)
-    ubr, ubc = uniq // nb, uniq % nb
-    indptr = np.zeros(mb_pad + 1, dtype=np.int64)
-    np.add.at(indptr, ubr + 1, 1)
-    indptr = np.cumsum(indptr)
-    rows_per = mb_pad // p
-    panel_of_block = ubr // rows_per
-    local_of_block = np.arange(len(uniq)) - indptr[panel_of_block * rows_per]
-    w = max(1, int(np.bincount(panel_of_block, minlength=p).max(initial=0)))
-    return (indptr, ubc.astype(np.int64), panel_of_block[inv],
-            local_of_block[inv], rows % bs, x.indices % bs, w)
+    uniq, block = np.unique((rows // bs) * nb + x.indices // bs,
+                            return_inverse=True)
+    mb = -(-m // bs)
+    indptr = np.zeros(mb + 1, np.int64)
+    np.cumsum(np.bincount(uniq // nb, minlength=mb), out=indptr[1:])
+    struct = BCSR(indptr, uniq % nb, np.zeros((0, bs, bs), np.float32),
+                  (m, n), bs)
+    return struct, rows, block.reshape(-1)
 
 
-def _struct_panels(indptr: np.ndarray, indices: np.ndarray, p: int, bs: int,
-                   ncols: int):
-    """Structure-only BCSR row panels (blocks empty; schedule construction
-    never reads them)."""
-    from .formats import BCSR
-    full = BCSR(indptr, indices, np.zeros((0, bs, bs), np.float32),
-                ((len(indptr) - 1) * bs, ncols), bs)
-    return bcsr_row_panels(full, p)
+def _by_device(device: np.ndarray, p: int, columns, fills) -> np.ndarray:
+    """int64 ``(p, len(columns), width)``: each column's items grouped by
+    ``device`` in their order, each device's row padded with its fill."""
+    from repro.kernels.masked_matmul.ops import local_positions
+    local, counts = local_positions(device, p)
+    out = np.empty((p, len(columns), max(1, int(counts.max(initial=0)))),
+                   np.int64)
+    for i, (col, fill) in enumerate(zip(columns, fills)):
+        out[:, i, :] = fill
+        out[device, i, local] = col
+    return out
+
+
+def _entry_scatter(x: CSR, bs: int, owner: np.ndarray, p: int):
+    """Where each stored entry of ``x`` goes on the ring.
+
+    Returns ``(struct, at, perm, counts)``: device ``d`` scatters its
+    entries' values ``x.data[perm[d]]`` into its blocks at
+    ``at[d] = (block, row, lane)``; padding points past the last block
+    (dropped by the scatter) and at entry ``x.nnz``, a zero appended to the
+    values.  ``counts[d]`` blocks of ``x`` lie on device ``d``."""
+    from repro.kernels.masked_matmul.ops import (block_devices,
+                                                 local_positions)
+    struct, rows, block = _block_structure(x, bs)
+    device = block_devices(struct.indptr, owner)
+    local, counts = local_positions(device, p)
+    r, c = block_position(rows % bs, x.indices % bs, bs)
+    width = max(1, int(counts.max(initial=0)))
+    grouped = _by_device(device[block], p,
+                         (local[block], r, c, np.arange(x.nnz)),
+                         (width, 0, 0, x.nnz))
+    return struct, grouped[:, :3].astype(np.int32), grouped[:, 3], counts
 
 
 #: host-prep cache for the sparse ring, keyed on operand *structure*
 #: (CRC signatures) + block size + ring size: schedules, scatter
 #: coordinates, and extraction addressing are all structure-pure, so
 #: repeated structures (the serving case; every plan-cache hit) skip
-#: straight to the value scatter + device program.  Capacity:
+#: straight to the value gather + device program.  Capacity:
 #: $REPRO_RING_PREP_CAP or ``repro.caches.set_capacity("ring-prep", n)``.
 _ring_prep_cache = caches.LRUCache("ring-prep", 32,
                                    env_var="REPRO_RING_PREP_CAP")
@@ -351,64 +396,53 @@ _ring_prep_cache = caches.LRUCache("ring-prep", 32,
 def _ring_prep(A: CSR, B: CSR, M: CSR, bs: int, p: int,
                wm: Optional[int]) -> dict:
     from repro.core.planner import structure_signature
-    from repro.kernels.masked_matmul.ops import build_ring_schedules
+    from repro.kernels.masked_matmul.ops import (block_devices,
+                                                 build_ring_schedules,
+                                                 local_positions)
 
     key = (structure_signature(A), structure_signature(B),
            structure_signature(M), bs, p, wm)
-    # host prep is pure structure arithmetic (panelization, scatter maps,
+    # host prep is pure structure arithmetic (partition, scatter maps,
     # ring schedules) — it embeds no cost-model decision, so a
     # calibration change cannot stale it; deliberately token-free
     hit = _ring_prep_cache.get(key)  # lint: plan-key-ok(structure-pure prep)
     if hit is not None:
         return hit
 
-    m, k = A.shape
-    n = B.shape[1]
-    a_ptr, a_idx, a_pan, a_loc, a_r, a_c, wa = _panel_scatter(A, bs, p)
-    b_ptr, b_idx, b_pan, b_loc, b_r, b_c, wb = _panel_scatter(B, bs, p)
-    m_ptr, m_idx, m_pan, m_loc, m_r, m_c, wmb = _panel_scatter(M, bs, p)
+    with obs.span("spgemm.ring_prep", p=p, bs=bs) as sp:
+        m = A.shape[0]
+        mb = -(-m // bs)
+        owner = ring_owner(mb, p)
+        k_owner = ring_owner(-(-B.shape[0] // bs), p)
+        Ab, a_at, a_perm, a_counts = _entry_scatter(A, bs, owner, p)
+        Bb, b_at, b_perm, b_counts = _entry_scatter(B, bs, k_owner, p)
+        Mb, rows, block = _block_structure(M, bs)
+        sched, entries = build_ring_schedules(Ab, Bb, Mb, owner, k_owner, p)
 
-    A_panels = _struct_panels(a_ptr, a_idx, p, bs, k)
-    B_slabs = _struct_panels(b_ptr, b_idx, p, bs, n)
-    M_panels = _struct_panels(m_ptr, m_idx, p, bs, n)
-    sched = build_ring_schedules(A_panels, B_slabs, M_panels, out_pad=wmb)
-
-    # stored-entry pattern panels are structure-constant: build once
-    a_pat = np.zeros((p, wa, bs, bs), np.float32)
-    a_pat[a_pan, a_loc, a_r, a_c] = 1.0
-    b_pat = np.zeros((p, wb, bs, bs), np.float32)
-    b_pat[b_pan, b_loc, b_r, b_c] = 1.0
-
-    # extraction: group mask elements by owning panel; each device
-    # scatters its own elements into its (rows_loc, pm) output shard.
-    # Padding entries point at row rows_loc -> dropped by scatter mode.
-    mr = np.repeat(np.arange(m, dtype=np.int64), np.diff(M.indptr))
-    slots = np.arange(M.nnz, dtype=np.int64) - M.indptr[mr]
-    M_p = padded_from_csr(M, wm)
-    rows_per = (len(m_ptr) - 1) // p
-    rows_loc = rows_per * bs
-    counts = np.bincount(m_pan, minlength=p)
-    max_e = max(1, int(counts.max(initial=0)))
-    order = np.argsort(m_pan, kind="stable")
-    j = np.arange(M.nnz) - np.concatenate(
-        [[0], np.cumsum(counts)[:-1]])[m_pan[order]]
-    pan_o = m_pan[order]
-
-    def panelized(values, fill):
-        out = np.full((p, max_e), fill, np.int32)
-        out[pan_o, j] = values[order]
-        return out
-
-    prep = dict(
-        a_scatter=(a_pan, a_loc, a_r, a_c, wa), a_pat=a_pat,
-        b_scatter=(b_pan, b_loc, b_r, b_c, wb), b_pat=b_pat,
-        sched=sched, wm_blocks=wmb, rows_loc=rows_loc,
-        ex_loc=panelized(m_loc, 0),
-        ex_roff=panelized(mr % bs, 0),
-        ex_coff=panelized(m_c, 0),
-        ex_rowl=panelized(mr - m_pan * rows_loc, rows_loc),
-        ex_slot=panelized(slots, 0),
-        mask_cols=M_p.cols, pm=M_p.width)
+        # extraction: each device scatters its own mask elements into its
+        # (rows_loc, pm) output shard, block row by block row in its order
+        device = block_devices(Mb.indptr, owner)
+        m_loc, m_counts = local_positions(device, p)
+        row_loc, row_counts = local_positions(owner, p)
+        rows_loc = int(row_counts.max()) * bs
+        local_row = row_loc[rows // bs] * bs + rows % bs
+        r, c = block_position(rows % bs, M.indices % bs, bs)
+        slots = np.arange(M.nnz, dtype=np.int64) - M.indptr[rows]
+        ex = _by_device(device[block], p,
+                        (m_loc[block], r, c, local_row, slots),
+                        (0, 0, 0, rows_loc, 0)).astype(np.int32)
+        g = np.arange(m, dtype=np.int64)
+        row_at = owner[g // bs] * rows_loc + row_loc[g // bs] * bs + g % bs
+        M_p = padded_from_csr(M, wm)
+        prep = dict(
+            a_at=a_at, a_perm=a_perm, b_at=b_at, b_perm=b_perm, sched=sched,
+            ex=ex, row_at=row_at.astype(np.int32), rows_loc=rows_loc,
+            wa=max(1, int(a_counts.max())), wb=max(1, int(b_counts.max())),
+            wm=max(1, int(m_counts.max())), mask_cols=M_p.cols,
+            pm=M_p.width, on_mesh={})
+        sp.set(blocks=a_counts.tolist(), slab_blocks=b_counts.tolist(),
+               entries=entries.sum(axis=1).tolist(),
+               stage_entries=entries.max(axis=0).tolist())
     _ring_prep_cache.put(key, prep)  # lint: plan-key-ok(structure-pure prep)
     return prep
 
@@ -427,22 +461,26 @@ def ring_sparse_masked_spgemm(A: CSR, B: CSR, M: CSR, mesh: Mesh, *,
                               backend: Optional[str] = None,
                               interpret: Optional[bool] = None,
                               wm: Optional[int] = None) -> MaskedSpGEMMResult:
-    """C = M (.) (A B) on a sparse BCSR ring: A/M row-panels sharded over
+    """C = M (.) (A B) on a sparse BCSR ring: A/M row panels sharded over
     ``axis``, B's occupied K-slabs rotating via ``ppermute``.
 
-    Densify-free end to end: CSR operands scatter into occupied blocks,
-    every device holds only its row-panel of A/M and one rotating B slab
-    (values + stored-entry pattern, padded to the ring max so ``ppermute``
-    sees one static shape), and each stage replays a host-built K-slab
-    worklist on the block executor.  ``present`` comes from a structural
-    counting replay sharing the same schedules, so results are bitwise the
-    single-device ``masked_spgemm`` semantics, including cancellation and
-    explicitly stored zeros.
+    Block rows are dealt to the devices round robin (``ring_owner``), for
+    A and M and for B's K-slabs alike, which balances blocks and products
+    on degree-ordered graphs.  Densify-free end to end: each device is
+    sent its entries' values and builds its occupied blocks of A and of
+    its B slab (values + stored-entry pattern, padded to the ring max so
+    ``ppermute`` sees one static shape); each stage replays a host-built
+    worklist on the block executor, adding into the panel's accumulators
+    in place.  ``present`` comes from a structural counting replay sharing
+    the same schedules, so results are bitwise the single-device
+    ``masked_spgemm`` semantics, including cancellation and explicitly
+    stored zeros.
 
-    Host prep (schedules, scatter coordinates, extraction addressing) is
-    pure structure and cached by structural signature — repeated
-    structures, the serving case, pay only the value scatter and the
-    compiled device program.
+    Host prep (partition, schedules, scatter coordinates, extraction
+    addressing, in the ``spgemm.ring_prep`` span) is pure structure and
+    cached by structural signature, its device copies per mesh: repeated
+    structures, the serving case, pay only the value gather
+    (``spgemm.host_prep``) and the compiled device program.
 
     Only ``plus_times`` with an explicit mask is supported (the executors
     accumulate with a dense dot) — ``distributed_masked_spgemm`` routes
@@ -468,21 +506,26 @@ def ring_sparse_masked_spgemm(A: CSR, B: CSR, M: CSR, mesh: Mesh, *,
     backend, it = resolve_executor(backend, interpret)
 
     prep = _ring_prep(A, B, M, bs, p, wm)
-    a_pan, a_loc, a_r, a_c, wa = prep["a_scatter"]
-    b_pan, b_loc, b_r, b_c, wb = prep["b_scatter"]
-    wm_blocks = prep["wm_blocks"]
-    a_vals = np.zeros((p, wa, bs, bs), np.float32)
-    a_vals[a_pan, a_loc, a_r, a_c] = A.data
-    b_vals = np.zeros((p, wb, bs, bs), np.float32)
-    b_vals[b_pan, b_loc, b_r, b_c] = B.data
+    sharded = NamedSharding(mesh, P(axis))
+    with obs.span("spgemm.host_prep", algorithm="ring"):
+        a_vals = np.append(A.data, 0).astype(np.float32)[prep["a_perm"]]
+        b_vals = np.append(B.data, 0).astype(np.float32)[prep["b_perm"]]
+        a_vals = to_device(a_vals, sharding=sharded)
+        b_vals = to_device(b_vals, sharding=sharded)
+    held = prep["on_mesh"].get((mesh, axis))
+    if held is None:
+        held = prep["on_mesh"][mesh, axis] = (
+            tuple(to_device(prep[k], sharding=sharded)
+                  for k in ("a_at", "b_at", "sched", "ex")),
+            to_device(prep["row_at"], sharding=NamedSharding(mesh, P())))
+    (a_at, b_at, sched, ex), row_at = held
 
-    run = _ring_sparse_program(mesh, axis, p, bs, wm_blocks, prep["pm"],
-                               prep["rows_loc"], backend, it)
-    vals, present = run(a_vals, prep["a_pat"], b_vals, prep["b_pat"],
-                        prep["sched"], prep["ex_loc"], prep["ex_roff"],
-                        prep["ex_coff"], prep["ex_rowl"], prep["ex_slot"])
-    return MaskedSpGEMMResult(vals[:m], present[:m], prep["mask_cols"],
-                              (m, n))
+    run = _ring_sparse_program(mesh, axis, p, bs, prep["wa"], prep["wb"],
+                               prep["wm"], prep["pm"], prep["rows_loc"],
+                               backend, it)
+    vals, present = _unpanel(*run(a_at, a_vals, b_at, b_vals, sched, ex),
+                             row_at)
+    return MaskedSpGEMMResult(vals, present, prep["mask_cols"], (m, n))
 
 
 # ---------------------------------------------------------------------------
@@ -576,6 +619,16 @@ def distributed_masked_spgemm(A: CSR, B: CSR, M: CSR, mesh: Mesh, *,
     return MaskedSpGEMMResult(vals[:m], present[:m], M_p.cols[:m], (m, n))
 
 
+@functools.lru_cache(maxsize=8)
+def device_mesh(devices: int, axis: str = "data") -> Mesh:
+    """A 1-D mesh over the first ``devices`` devices, one object per size
+    so the compiled programs' caches, keyed on the mesh, stay warm."""
+    avail = jax.devices()
+    if not 1 <= devices <= len(avail):
+        raise ValueError(f"{devices} devices asked for, {len(avail)} found")
+    return Mesh(np.array(avail[:devices]), (axis,))
+
+
 # ---------------------------------------------------------------------------
 # helpers for building sharded problems
 # ---------------------------------------------------------------------------
@@ -586,6 +639,7 @@ def distributed_masked_spgemm(A: CSR, B: CSR, M: CSR, mesh: Mesh, *,
 caches.register_lru("dist-row-program", _row_parallel_program)
 caches.register_lru("dist-dense-ring-program", _ring_dense_program)
 caches.register_lru("dist-sparse-ring-program", _ring_sparse_program)
+caches.register_lru("dist-device-mesh", device_mesh)
 
 
 def pad_rows_to(mesh_axis_size: int, *mats: PaddedCSR) -> Tuple[PaddedCSR, ...]:

@@ -92,14 +92,19 @@ def _expand_rows(indptr: np.ndarray) -> np.ndarray:
     return np.repeat(np.arange(len(counts), dtype=np.int64), counts)
 
 
-def to_device(x, dtype=None) -> Array:
-    """``jnp.asarray(x, dtype)``: one host-to-device copy, under an
-    ``spgemm.h2d`` span whose ``bytes`` are the array's size as it lands
-    on the device.  The span times the host's part of the call, which
-    may return before the transfer has finished."""
+def to_device(x, dtype=None, sharding=None) -> Array:
+    """``jnp.asarray(x, dtype)``, or with a ``sharding`` ``device_put``
+    there: one host-to-device copy, under an ``spgemm.h2d`` span whose
+    ``bytes`` are what lands on the devices (a replicated array once per
+    device).  The span times the host's part of the call, which may
+    return before the transfer has finished."""
     with obs.span("spgemm.h2d") as sp:
-        out = jnp.asarray(x, dtype)
-        sp.set(bytes=out.nbytes)
+        if sharding is None:
+            out = jnp.asarray(x, dtype)
+            sp.set(bytes=out.nbytes)
+        else:
+            out = jax.device_put(np.asarray(x, dtype), sharding)
+            sp.set(bytes=sum(s.data.nbytes for s in out.addressable_shards))
     return out
 
 

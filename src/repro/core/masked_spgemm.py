@@ -118,7 +118,8 @@ def masked_spgemm(A, B, M, *, algorithm: str = "auto",
                   semiring: Semiring = PLUS_TIMES, complement: bool = False,
                   two_phase: bool = False, n_inspect: Optional[int] = None,
                   widths: Optional[Tuple[int, int, int]] = None,
-                  tile_block: Optional[int] = None, plan=None):
+                  tile_block: Optional[int] = None, plan=None,
+                  devices: int = 1):
     """C = M (.) (A B)   [or  C = (not M) (.) (A B)].
 
     A, B, M: host CSR (or PaddedCSR already on device).  Returns a
@@ -138,10 +139,26 @@ def masked_spgemm(A, B, M, *, algorithm: str = "auto",
     route (``tile_block`` picks the block size; plus_times, explicit mask,
     host-CSR operands only).  A precomputed ``plan`` (from
     ``planner.plan``) overrides ``algorithm`` and ``widths``.
+
+    ``devices > 1`` runs the product on a 1-D mesh over the first
+    ``devices`` devices through ``distributed_masked_spgemm``:
+    ``algorithm`` is ``"ring"`` (the sparse BCSR ring, ``tile_block`` its
+    block size), ``"row"`` or ``"auto"``; host CSR operands only.  It
+    raises where the mesh path cannot honour a request: ``two_phase``, a
+    plan or widths, a complemented mask, another algorithm, and the ring
+    under a semiring other than plus_times.
     """
     m, k = A.shape
     k2, n = B.shape
     assert k == k2, (A.shape, B.shape)
+    if devices != 1:
+        if two_phase or plan is not None or widths is not None:
+            raise NotImplementedError(
+                "two_phase, plan and widths are single-device options")
+        from .distributed import device_mesh, distributed_masked_spgemm
+        return distributed_masked_spgemm(
+            A, B, M, device_mesh(devices), algorithm=algorithm,
+            semiring=semiring, complement=complement, block_size=tile_block)
     if two_phase and algorithm == "tile":
         # the tile route's symbolic phase is the host schedule build; a 2P
         # padded-width pass has no meaning there, and silently ignoring the

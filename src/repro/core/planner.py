@@ -443,7 +443,9 @@ def ring_cost_features(stats: PlanStats, p: int, bs: int
     the padded value+pattern B slab panel.
     """
     m_blocks, b_blocks, pair = _block_counts(stats, bs)
-    worklist = m_blocks * pair + p * m_blocks  # + zero-fills/stage
+    # real products only: the ring's stages add into their accumulators
+    # in place, so no stage replays zero-fill entries
+    worklist = m_blocks * pair
     tile_f = _tile_feature_dict(stats, worklist, bs, float(p))
     # one padded slab panel (values + pattern blocks) moves per rotation;
     # both ring implementations peel the final stage, so p stages transmit

@@ -21,6 +21,9 @@ footprint per step is bm*bk + bk*bn + bm*bn words.
 """
 from __future__ import annotations
 
+import functools
+from typing import Tuple
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -81,8 +84,50 @@ def masked_matmul_kernel(a, b, bi, bj, *, bm, bn, bk, out_dtype=jnp.float32,
 # ---------------------------------------------------------------------------
 
 
+def block_layout(bs: int) -> Tuple[int, int]:
+    """``(rows, lanes)`` in which one ``bs x bs`` block is stored.
+
+    The TPU lays out the last two dims of an f32 array in (8, 128) tiles,
+    so a (32, 32) block takes a (32, 128) tile row: four times its bytes.
+    Where a block's words fill whole tiles (bs 32 and 64) it can be stored
+    lane-dense as ``(bs * bs // 128, 128)``; other sizes keep
+    ``(bs, bs)``.  Element ``(r, c)`` of a block lies at
+    ``(r % rows, (r // rows) * bs + c)`` (``block_position``).
+    """
+    if bs < 128 and (bs * bs) % (8 * 128) == 0:
+        return bs * bs // 128, 128
+    return bs, bs
+
+
+def block_position(r, c, bs: int):
+    """Where element ``(r, c)`` of a block lies in ``block_layout(bs)``."""
+    rows, _ = block_layout(bs)
+    return r % rows, (r // rows) * bs + c
+
+
+def unpack_blocks(x, bs: int):
+    """``(..., rows, lanes)`` stored blocks -> ``(..., bs, bs)``: the lane
+    groups of width ``bs`` stacked along the rows (static slices only, so
+    Mosaic compiles it inside a kernel)."""
+    lanes = x.shape[-1]
+    if lanes == bs:
+        return x
+    return jnp.concatenate([x[..., j * bs:(j + 1) * bs]
+                            for j in range(lanes // bs)], axis=-2)
+
+
+def pack_blocks(x, rows: int):
+    """Inverse of ``unpack_blocks``: ``(..., bs, bs)`` -> stored blocks."""
+    bs = x.shape[-1]
+    if rows == bs:
+        return x
+    return jnp.concatenate([x[..., j * rows:(j + 1) * rows, :]
+                            for j in range(bs // rows)], axis=-1)
+
+
 def _block_spgemm_body(rank_ref, pa_ref, pb_ref, flags_ref,
-                       a_ref, b_ref, _carried_ref, o_ref, acc_ref):
+                       a_ref, b_ref, carried_ref, o_ref, acc_ref, *,
+                       bs, accumulate):
     w = pl.program_id(0)
     first = flags_ref[w] & 1
     real = (flags_ref[w] >> 1) & 1
@@ -90,49 +135,58 @@ def _block_spgemm_body(rank_ref, pa_ref, pb_ref, flags_ref,
 
     @pl.when(first == 1)
     def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        if accumulate:
+            acc_ref[...] = unpack_blocks(carried_ref[0], bs)
+        else:
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(real == 1)
     def _mac():
-        acc_ref[...] += jnp.dot(a_ref[0], b_ref[0],
+        acc_ref[...] += jnp.dot(unpack_blocks(a_ref[0], bs),
+                                unpack_blocks(b_ref[0], bs),
                                 preferred_element_type=jnp.float32)
 
     @pl.when(last == 1)
     def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)[None]
+        o_ref[...] = pack_blocks(acc_ref[...], o_ref.shape[1]).astype(
+            o_ref.dtype)[None]
 
 
 def block_spgemm_kernel(a_blocks, b_blocks, rank, pa, pb, flags, out,
-                        *, bs, interpret=False):
+                        *, bs, interpret=False, accumulate=False):
     """Masked BCSR product from one worklist chunk, replayed into ``out``.
 
-    a_blocks: (nnzb_a, bs, bs); b_blocks: (nnzb_b, bs, bs).
+    a_blocks: (nnzb_a, *block); b_blocks: (nnzb_b, *block); out:
+    (nnzb_out, *block) f32, where ``block`` is ``(bs, bs)`` or the
+    lane-dense ``block_layout(bs)`` (the kernel unpacks each block it
+    reads and packs each it writes).
     rank/pa/pb: (W,) int32 — output block rank and A/B block positions.
     flags: (W,) int32 bitfield — 1=first visit of rank, 2=real product
       (0 -> zero-fill entry for a mask block with no contribution),
       4=last visit of rank (flush accumulator to HBM).
     The worklist MUST be sorted by rank (sequential-grid accumulation) and
-    must not end inside a rank.  ``out`` (nnzb_out, bs, bs) f32 is aliased
-    to the result: blocks the chunk does not visit keep their values, which
-    is what lets ``ops.replay_chunks`` split a long worklist into calls
-    whose scalar-prefetch arrays fit in SMEM.
+    must not end inside a rank.  ``out`` is aliased to the result: blocks
+    the chunk does not visit keep their values, which is what lets
+    ``ops.replay_chunks`` split a long worklist into calls whose
+    scalar-prefetch arrays fit in SMEM.  With ``accumulate`` a rank's
+    first visit starts from its block in ``out`` instead of zero, so a
+    replay adds into ``out`` in place.
     """
     W = rank.shape[0]
+    spec = functools.partial(pl.BlockSpec, (1,) + tuple(out.shape[1:]))
+    at_rank = spec(lambda w, r_r, pa_r, pb_r, f_r: (r_r[w], 0, 0))
     return pl.pallas_call(
-        _block_spgemm_body,
+        functools.partial(_block_spgemm_body, bs=bs, accumulate=accumulate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(W,),
             in_specs=[
-                pl.BlockSpec((1, bs, bs),
-                             lambda w, r_r, pa_r, pb_r, f_r: (pa_r[w], 0, 0)),
-                pl.BlockSpec((1, bs, bs),
-                             lambda w, r_r, pa_r, pb_r, f_r: (pb_r[w], 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),   # aliased, never read
+                spec(lambda w, r_r, pa_r, pb_r, f_r: (pa_r[w], 0, 0)),
+                spec(lambda w, r_r, pa_r, pb_r, f_r: (pb_r[w], 0, 0)),
+                # ``out``: read only to accumulate
+                at_rank if accumulate else pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, bs, bs),
-                                   lambda w, r_r, pa_r, pb_r, f_r:
-                                   (r_r[w], 0, 0)),
+            out_specs=at_rank,
             scratch_shapes=[pltpu.VMEM((bs, bs), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(out.shape, out.dtype),

@@ -198,83 +198,84 @@ def _spgemm_schedule(A: BCSR, B: BCSR, M: BCSR) -> Schedule:
 
 
 # ---------------------------------------------------------------------------
-# K-slab schedules (distributed ring-SUMMA): one worklist per ring stage
+# Ring schedules (distributed ring-SUMMA): one worklist per device and stage
 # ---------------------------------------------------------------------------
 
 
-def build_spgemm_schedule_slab(A: BCSR, B_slab: BCSR, M: BCSR,
-                               k0_blocks: int) -> Schedule:
-    """Worklist for C = M (.) (A[:, slab] @ B_slab), one ring stage.
+def block_devices(indptr: np.ndarray, owner: np.ndarray) -> np.ndarray:
+    """The device holding each block (CSR order) of a block structure
+    whose block row ``i`` lies on device ``owner[i]``."""
+    return np.repeat(np.asarray(owner, np.int64), np.diff(indptr))
 
-    ``B_slab`` holds block rows [k0_blocks, k0_blocks + B_slab.block_rows)
-    of the full B, rebased to start at 0 (its ``pb`` positions index the
-    slab's own blocks).  ``pa`` positions index the full panel ``A.blocks``.
-    Zero-fill semantics match ``build_spgemm_schedule``: every mask block
-    gets at least one entry, so a per-stage executor's output is fully
-    defined even for stages whose slab contributes nothing.
+
+def local_positions(device: np.ndarray, p: int
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+    """``(local, counts)`` for items dealt to ``p`` devices: item ``i``
+    lies on ``device[i]`` at position ``local[i]`` of that device's items,
+    which keep their order; ``counts[d]`` items lie on device ``d``."""
+    counts = np.bincount(device, minlength=p)
+    order = np.argsort(device, kind="stable")
+    local = np.empty(len(device), np.int64)
+    local[order] = (np.arange(len(device))
+                    - np.repeat(np.cumsum(counts) - counts, counts))
+    return local, counts
+
+
+def build_ring_schedules(A: BCSR, B: BCSR, M: BCSR, owner: np.ndarray,
+                         k_owner: np.ndarray, p: int
+                         ) -> Tuple[np.ndarray, np.ndarray]:
+    """Per-device, per-stage chunked worklists for the sparse ring.
+
+    Device ``d`` holds the block rows ``owner == d`` of A and M (its row
+    panel) and, at ring stage ``s``, B's K-slab ``(d - s) % p``: the
+    block rows ``k_owner == (d - s) % p`` of B.  The full worklist of
+    ``build_spgemm_schedule`` is split by (device, stage) and its
+    positions made local to the panel and the slab, so each stage's
+    entries stay sorted by rank.  Only real products are kept: the ring
+    replays with ``accumulate``, where a rank no entry visits keeps its
+    value, so no zero-fill entries are needed.
+
+    Returns ``(chunks, entries)``: int32 ``(p, p, n_chunks, 4, length)``,
+    where ``[d, s]`` is device ``d``'s worklist at stage ``s`` split by
+    ``chunk_schedule`` into chunks of one static length (stages with
+    fewer chunks than the longest are padded with chunks whose flags are
+    all off, which ``replay_chunks`` never launches), and ``(p, p)``
+    products per device and stage.
     """
-    rows_slab = B_slab.block_rows
-    in_slab = (A.indices >= k0_blocks) & (A.indices < k0_blocks + rows_slab)
-    pos_map = np.nonzero(in_slab)[0]
-    brow = np.repeat(np.arange(A.block_rows, dtype=np.int64),
-                     np.diff(A.indptr))[in_slab]
-    indptr_sub = np.zeros(A.block_rows + 1, dtype=np.int64)
-    np.add.at(indptr_sub, brow + 1, 1)
-    A_sub = BCSR(np.cumsum(indptr_sub), A.indices[in_slab] - k0_blocks,
-                 A.blocks, (A.shape[0], rows_slab * A.block_size),
-                 A.block_size)
-    rank, pa, pb, flags = build_spgemm_schedule(A_sub, B_slab, M)
-    # remap pa from slab-filtered positions back to the full panel's blocks
-    # (zero-fill entries keep position 0 — they never contribute)
-    real = (flags >> 1) & 1
-    if len(pos_map):
-        pa = np.where(real == 1, pos_map[np.minimum(pa, len(pos_map) - 1)],
-                      0).astype(np.int32)
-    else:
-        pa = np.zeros_like(pa)
-    return rank, pa, pb, flags
-
-
-def build_ring_schedules(A_panels, B_slabs, M_panels, *, out_pad: int
-                         ) -> np.ndarray:
-    """Stacked per-device, per-stage chunked worklists for the sparse ring.
-
-    Returns int32 ``(p, p, n_chunks, 4, length)``: ``[d, s]`` holds the
-    worklist ``(rank, pa, pb, flags)`` device ``d`` replays at ring stage
-    ``s``, when it holds B K-slab ``(d - s) % p``, split by
-    ``chunk_schedule`` into chunks of one static length.  Ranks
-    ``[nnzb(M_panel), out_pad)`` (the ring-wide output padding) get
-    zero-fill entries (flags first|last, real off), so every output rank
-    of every stage is written.  Stages with fewer chunks than the ring's
-    longest are padded with chunks whose flags are all off, which
-    ``replay_chunks`` never launches.
-    """
-    p = len(A_panels)
-    assert len(B_slabs) == len(M_panels) == p
-    slab_rows = B_slabs[0].block_rows
-    scheds = {}
-    for d in range(p):
-        for s in range(p):
-            src = (d - s) % p
-            rank, pa, pb, flags = build_spgemm_schedule_slab(
-                A_panels[d], B_slabs[src], M_panels[d], src * slab_rows)
-            nloc = M_panels[d].nnzb
-            if out_pad > nloc:
-                extra = np.arange(nloc, out_pad, dtype=np.int32)
-                z = np.zeros(len(extra), np.int32)
-                rank = np.concatenate([rank, extra])
-                pa = np.concatenate([pa, z])
-                pb = np.concatenate([pb, z])
-                flags = np.concatenate([flags, np.full(len(extra), 5,
-                                                       np.int32)])
-            scheds[d, s] = (rank, pa, pb, flags)
-    length = min(SPGEMM_CHUNK, max(len(w[0]) for w in scheds.values()))
-    chunked = {ds: chunk_schedule(w, length) for ds, w in scheds.items()}
-    n_chunks = max(len(c) for c in chunked.values())
+    rank, pa, pb, flags = build_spgemm_schedule(A, B, M)
+    real = ((flags >> 1) & 1) == 1
+    rank, pa, pb = rank[real], pa[real], pb[real]
+    m_dev = block_devices(M.indptr, owner)
+    b_dev = block_devices(B.indptr, k_owner)
+    m_loc, _ = local_positions(m_dev, p)
+    a_loc, _ = local_positions(block_devices(A.indptr, owner), p)
+    b_loc, _ = local_positions(b_dev, p)
+    dev = m_dev[rank]
+    group = dev * p + (dev - b_dev[pb]) % p          # device, stage
+    order = np.argsort(group, kind="stable")
+    group = group[order]
+    rank, pa, pb = m_loc[rank[order]], a_loc[pa[order]], b_loc[pb[order]]
+    bounds = np.searchsorted(group, np.arange(p * p + 1))
+    entries = np.diff(bounds).reshape(p, p)
+    length = max(1, min(SPGEMM_CHUNK, int(entries.max(initial=0))))
+    chunked = {}
+    for g in range(p * p):
+        lo, hi = bounds[g], bounds[g + 1]
+        if hi == lo:
+            continue
+        r = rank[lo:hi]
+        first = np.ones(len(r), bool)
+        np.not_equal(r[1:], r[:-1], out=first[1:])
+        last = np.ones(len(r), bool)
+        last[:-1] = first[1:]
+        fl = first * 1 + 2 + last * 4
+        chunked[divmod(g, p)] = chunk_schedule((r, pa[lo:hi], pb[lo:hi], fl),
+                                               length)
+    n_chunks = max([1] + [len(c) for c in chunked.values()])
     out = np.zeros((p, p, n_chunks, 4, length), np.int32)
     for (d, s), c in chunked.items():
         out[d, s, :len(c)] = c
-    return out
+    return out, entries
 
 
 # ---------------------------------------------------------------------------
@@ -323,18 +324,22 @@ def chunk_schedule(schedule: Schedule, length: int) -> np.ndarray:
     return out
 
 
-def replay_chunks(out, a_blocks, b_blocks, chunks, *, bs, interpret):
+def replay_chunks(out, a_blocks, b_blocks, chunks, *, bs, interpret,
+                  accumulate=False):
     """Replay a chunked worklist (``chunk_schedule``) into ``out`` on the
     Pallas kernel, one call per chunk, each call aliasing ``out`` so the
-    blocks it does not visit survive.  Chunks whose flags are all off
-    (the ring's stage padding) are never launched: a Pallas output block
-    is written back on every visit, even one whose body never stored."""
+    blocks it does not visit survive (with ``accumulate``, the blocks it
+    visits gain the products instead of being replaced by them).  Chunks
+    whose flags are all off (the ring's stage padding) are never
+    launched: a Pallas output block is written back on every visit, even
+    one whose body never stored."""
     n_real = jnp.sum(jnp.any(chunks[:, 3, :] != 0, axis=1))
 
     def body(c, out):
         rank, pa, pb, flags = (chunks[c, i] for i in range(4))
         return block_spgemm_kernel(a_blocks, b_blocks, rank, pa, pb, flags,
-                                   out, bs=bs, interpret=interpret)
+                                   out, bs=bs, interpret=interpret,
+                                   accumulate=accumulate)
 
     return jax.lax.fori_loop(0, n_real, body, out)
 

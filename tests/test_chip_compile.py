@@ -73,27 +73,50 @@ def test_block_spgemm_compiles_past_one_call_of_smem(one_chip, bs):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_ring_stage_compiles_on_four_chip_mesh(topo):
-    """The sparse ring's shard program, Pallas stages included, with each
-    stage's worklist longer than one kernel call."""
+def _ring_program(topo, *, blocks, entries, chunks, pm, rows_loc):
+    """The sparse ring's shard program on four chips, Pallas stages
+    included, compiled at the given per-device sizes: ``blocks`` of A's
+    panel, B's slab and M's panel alike, ``entries`` of A, B and the mask,
+    ``chunks`` worklist chunks a stage."""
     from repro.core.distributed import _ring_sparse_program
     from repro.kernels.masked_matmul.ops import SPGEMM_CHUNK
-    p, bs, wa, wm, pm, rows_loc, n_ex = 4, 32, 512, 512, 64, 4096, 8192
+    p, bs = 4, 32
     mesh = Mesh(np.array(topo.devices[:p]), ("data",))
     sharded = NamedSharding(mesh, P("data"))
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharded)
 
-    blocks = arg((p, wa, bs, bs), jnp.float32)
-    sched = arg((p, p, 3, 4, SPGEMM_CHUNK), jnp.int32)
-    extract = [arg((p, n_ex), jnp.int32) for _ in range(5)]
-    run = _ring_sparse_program(mesh, "data", p, bs, wm, pm, rows_loc,
-                               "pallas", False)
-    text = run.lower(blocks, blocks, blocks, blocks, sched,
-                     *extract).compile().as_text()
+    at, vals = arg((p, 3, entries), jnp.int32), arg((p, entries), jnp.float32)
+    sched = arg((p, p, chunks, 4, SPGEMM_CHUNK), jnp.int32)
+    run = _ring_sparse_program(mesh, "data", p, bs, blocks, blocks, blocks,
+                               pm, rows_loc, "pallas", False)
+    return run.lower(at, vals, at, vals, sched,
+                     arg((p, 5, entries), jnp.int32)).compile()
+
+
+def test_ring_stage_compiles_on_four_chip_mesh(topo):
+    """The sparse ring's shard program, Pallas stages included, with each
+    stage's worklist longer than one kernel call."""
+    text = _ring_program(topo, blocks=512, entries=8192, chunks=3, pm=64,
+                         rows_loc=4096).as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+#: per-device sizes of ``tc.kron-s17.ring`` (GAP kron at scale 17, bs 32,
+#: block rows dealt round robin over four chips): the largest panel holds
+#: 89,331 blocks and 467,148 entries, a stage at most 120 chunks; mask
+#: rows are at most 325 wide, a panel 1,024 block rows (32,768 rows)
+S17_RING = dict(blocks=89331, entries=467148, chunks=120, pm=325,
+                rows_loc=32768)
+
+
+def test_ring_program_fits_at_scale17(topo):
+    """At the scale-17 cell's shapes the ring program fits a v5e with
+    room: arguments plus temporaries at most 14 GB a device."""
+    mem = _ring_program(topo, **S17_RING).memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= 14e9
 
 
 def test_masked_matmul_compiles_at_128(one_chip):

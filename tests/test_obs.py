@@ -624,6 +624,24 @@ def _h2d_case(route, L):
                 + 2 * 4 * chunk_len * 4        # one chunk per replay
                 + padded(widest)               # the mask, padded
                 + 5 * L.nnz * 4)               # gather addressing
+    if route == "ring":
+        from repro.core.distributed import (clear_ring_prep_cache,
+                                            device_mesh,
+                                            ring_sparse_masked_spgemm)
+        Lb = bcsr_from_csr(L, 8)
+        flags = ops.build_spgemm_schedule(Lb, Lb, Lb)[3]
+        products = int(np.count_nonzero((flags >> 1) & 1))
+
+        def solve():
+            clear_ring_prep_cache()     # a structure's first call
+            ring_sparse_masked_spgemm(L, L, L, device_mesh(1), block_size=8)
+        return (solve,
+                2 * L.nnz * 4                  # A's and B's values
+                + 2 * 3 * L.nnz * 4            # their block coordinates
+                + 4 * min(ops.SPGEMM_CHUNK, products) * 4   # one chunk
+                + 5 * L.nnz * 4                # extraction addressing
+                + m * 4                        # rows back in mask order
+                + padded(widest))              # the mask, padded
     if route == "row":
         return (lambda: masked_spgemm(L, L, L, algorithm="inner"),
                 2 * padded(widest) + padded(widest_col))    # A, M; B^T
@@ -632,7 +650,7 @@ def _h2d_case(route, L):
             2 * (2 * padded(widest)) + padded(widest))      # A, M; B
 
 
-@pytest.mark.parametrize("route", ["tile", "row", "batched"])
+@pytest.mark.parametrize("route", ["tile", "row", "batched", "ring"])
 def test_h2d_bytes_are_the_device_operands(route):
     solve, want = _h2d_case(route, _lower())
     with obs.tracing() as tr:
@@ -711,3 +729,38 @@ def test_tracing_off_mirrors_nothing(tmp_path):
     events = _host_plane_events(tmp_path, lambda: _tile_solve(L, "xla"))
     names = {n for n, _, _ in events}
     assert not {n for n in names if n.startswith(("spgemm.", "graph."))}
+
+
+def test_ring_prep_and_host_prep_spans():
+    """The sparse ring's cold structure prep runs under one
+    ``spgemm.ring_prep`` span (ring size, block size, blocks and products
+    per device) and only on a structure's first call; the per-call value
+    gather runs under ``spgemm.host_prep`` on every call."""
+    from repro.core.distributed import (clear_ring_prep_cache, device_mesh,
+                                        ring_sparse_masked_spgemm)
+    from repro.core.formats import bcsr_from_csr
+    from repro.kernels.masked_matmul import ops
+    L = _lower()
+    clear_ring_prep_cache()
+    recs = []
+    for _ in range(2):
+        with obs.tracing() as tr:
+            ring_sparse_masked_spgemm(L, L, L, device_mesh(1), block_size=8)
+        recs.append(tr.sink.spans())
+    names = [[r["name"] for r in rs] for rs in recs]
+    assert names[0].count("spgemm.ring_prep") == 1
+    assert "spgemm.ring_prep" not in names[1]
+    for rs in recs:
+        (prep,) = [r for r in rs if r["name"] == "spgemm.host_prep"]
+        assert prep["attrs"] == {"algorithm": "ring"} and prep["dur"] > 0
+    (ring,) = [r for r in recs[0] if r["name"] == "spgemm.ring_prep"]
+    Lb = bcsr_from_csr(L, 8)
+    flags = ops.build_spgemm_schedule(Lb, Lb, Lb)[3]
+    products = int(np.count_nonzero((flags >> 1) & 1))
+    assert ring["attrs"] == {"p": 1, "bs": 8, "blocks": [Lb.nnzb],
+                             "slab_blocks": [Lb.nnzb],
+                             "entries": [products],
+                             "stage_entries": [products]}
+    # the schedule build is the prep's own, not a sibling of it
+    (sched,) = [r for r in recs[0] if r["name"] == "spgemm.schedule"]
+    assert sched["parent"] == ring["span"]

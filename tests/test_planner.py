@@ -327,29 +327,46 @@ def test_decide_distributed_prefers_ring_when_b_is_huge():
 
 
 def test_slab_schedules_partition_the_full_schedule():
-    """Per-slab worklists must partition the full schedule's real entries:
-    same total MAC count, same per-rank contribution counts."""
-    from repro.core.formats import (bcsr_from_csr, bcsr_pad_block_rows,
-                                    bcsr_row_panels)
-    from repro.kernels.masked_matmul.ops import (build_spgemm_schedule,
-                                                 build_spgemm_schedule_slab)
+    """The ring's per-device, per-stage worklists must partition the full
+    schedule's real entries: every product once, on the device owning
+    its mask block row, at the stage that device holds its K-slab, with
+    panel- and slab-local positions and each stage sorted by rank."""
+    from repro.core.distributed import ring_owner
+    from repro.core.formats import bcsr_from_csr
+    from repro.kernels.masked_matmul.ops import (block_devices,
+                                                 build_ring_schedules,
+                                                 build_spgemm_schedule,
+                                                 local_positions)
     rng = np.random.default_rng(31)
     dense = lambda m, n, d: ((rng.random((m, n)) < d) * 1.0
                              ).astype(np.float32)
     A = bcsr_from_csr(csr_from_dense(dense(40, 48, 0.2)), 8)
     B = bcsr_from_csr(csr_from_dense(dense(48, 40, 0.2)), 8)
     M = bcsr_from_csr(csr_from_dense(dense(40, 40, 0.4)), 8)
-    rank, _, _, flags = build_spgemm_schedule(A, B, M)
-    want = np.bincount(rank[((flags >> 1) & 1) == 1], minlength=M.nnzb)
+    rank, pa, pb, flags = build_spgemm_schedule(A, B, M)
+    real = ((flags >> 1) & 1) == 1
+    want = sorted(zip(rank[real], pa[real], pb[real]))
     p = 4
-    slabs = bcsr_row_panels(
-        bcsr_pad_block_rows(B, -(-B.block_rows // p) * p), p)
-    rows_per = slabs[0].block_rows
-    got = np.zeros(M.nnzb, np.int64)
-    for s, slab in enumerate(slabs):
-        r, pa, pb, fl = build_spgemm_schedule_slab(A, slab, M, s * rows_per)
-        real = ((fl >> 1) & 1) == 1
-        got += np.bincount(r[real], minlength=M.nnzb)
-        assert (np.diff(r) >= 0).all()        # rank-sorted per stage
-        assert pb.max(initial=0) <= max(0, slab.nnzb - 1)
-    np.testing.assert_array_equal(got, want)
+    owner, k_owner = ring_owner(M.block_rows, p), ring_owner(B.block_rows, p)
+    chunks, entries = build_ring_schedules(A, B, M, owner, k_owner, p)
+    # local position -> global position, per device
+    glob = {}
+    for name, x, own in (("m", M, owner), ("a", A, owner),
+                         ("b", B, k_owner)):
+        dev = block_devices(x.indptr, own)
+        loc, _ = local_positions(dev, p)
+        for g, (d, l) in enumerate(zip(dev, loc)):
+            glob[name, d, l] = g
+    got = []
+    for d in range(p):
+        for s in range(p):
+            src = (d - s) % p
+            c = chunks[d, s]
+            live = c[:, 3, :] != 0
+            r, a, b, f = (c[:, i, :][live] for i in range(4))
+            assert len(r) == entries[d, s]
+            assert (np.diff(r) >= 0).all()          # rank-sorted per stage
+            assert (((f >> 1) & 1) == 1).all()      # real products only
+            got += [(glob["m", d, ri], glob["a", d, ai], glob["b", src, bi])
+                    for ri, ai, bi in zip(r, a, b)]
+    assert sorted(got) == want

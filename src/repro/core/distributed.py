@@ -11,10 +11,11 @@ naturally across a mesh:
 * ``ring_sparse_masked_spgemm`` — 1.5D sparse ring-SUMMA on BCSR operands
   when B is too large to replicate: block rows are dealt to the devices
   round robin, A/M's as row panels and B's as *occupied* BCSR K-slabs
-  that rotate around the ring via ``jax.lax.ppermute`` (value+pattern
-  blocks, padded to the ring-wide max so every rotation has one static
-  shape).  Each stage replays a host-built worklist on the block
-  executors (Pallas on TPU, chunked XLA elsewhere) — no dense ``(k, n)``
+  that rotate around the ring via ``jax.lax.ppermute`` (value and pattern
+  stacked as two planes of one block array, padded to the ring-wide max
+  so every rotation has one static shape).  Each stage replays a
+  host-built worklist on the block executors, both planes in one walk
+  (Pallas on TPU, chunked XLA elsewhere) — no dense ``(k, n)``
   or ``(m, n)`` array exists anywhere on this path, which is what makes
   it usable at scales where ``ring_masked_matmul``'s dense operands
   would not fit.
@@ -49,8 +50,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro import caches
 from repro import obs
 
-from repro.kernels.masked_matmul.kernel import (block_layout, block_position,
-                                                pack_blocks, unpack_blocks)
+from repro.kernels.masked_matmul.kernel import block_layout, block_position
 
 from .formats import BCSR, CSR, PaddedCSR, padded_from_csr, to_device
 from .masked_spgemm import MaskedSpGEMMResult, _row_fn
@@ -209,32 +209,21 @@ def _ring_dense_program(mesh: Mesh, axis: str, block: int, precision):
 
 
 def _ring_stage_xla(out, a_blocks, b_blocks, chunks, *, bs):
-    """One ring stage on the chunked-XLA executor: gather, batched matmul,
-    segment-add into the running panel accumulator.  The stage's chunked
-    worklist is read flat (padding entries have the real-bit off), in
-    pieces like ``ops._block_spgemm_xla`` so peak memory stays
-    O(piece * bs^2)."""
-    from repro.kernels.masked_matmul.ops import _XLA_CHUNK_ELEMS
-    rows = out.shape[1]
+    """One ring stage on the chunked-XLA executor (``ops.block_spgemm_xla``):
+    the stage's chunked worklist read flat (padding entries have the
+    real-bit off), its products added plane by plane into the running
+    panel accumulator."""
+    from repro.kernels.masked_matmul.ops import block_spgemm_xla
     rank, pa, pb, flags = (chunks[:, i, :].reshape(-1) for i in range(4))
-    ws = int(rank.shape[0])
-    piece = max(1, _XLA_CHUNK_ELEMS // (bs * bs))
-    for s0 in range(0, ws, piece):
-        e = min(ws, s0 + piece)
-        real = ((flags[s0:e] >> 1) & 1).astype(jnp.float32)
-        prods = jnp.einsum("wij,wjk->wik",
-                           unpack_blocks(a_blocks[pa[s0:e]], bs),
-                           unpack_blocks(b_blocks[pb[s0:e]], bs),
-                           preferred_element_type=jnp.float32)
-        out = out.at[rank[s0:e]].add(
-            pack_blocks(prods * real[:, None, None], rows))
-    return out
+    return block_spgemm_xla(out, a_blocks, b_blocks, rank, pa, pb, flags,
+                            bs=bs)
 
 
 def _ring_stage_pallas(out, a_blocks, b_blocks, chunks, *, bs, interpret):
     """One ring stage on the Pallas executor: the replay adds each visited
-    rank's products into the running accumulator in place
-    (``accumulate``); ranks the stage does not visit keep their value."""
+    rank's products, every plane in one walk, into the running
+    accumulator in place (``accumulate``); ranks the stage does not visit
+    keep their value."""
     from repro.kernels.masked_matmul.ops import replay_chunks
     return replay_chunks(out, a_blocks, b_blocks, chunks, bs=bs,
                          interpret=interpret, accumulate=True)
@@ -249,13 +238,16 @@ def _ring_sparse_program(mesh: Mesh, axis: str, p: int, bs: int, wa: int,
     the jit cache; only the quantities baked into the trace are keys here.
 
     Each device builds its blocks from the entries it is sent (values and
-    their block coordinates, ``_ring_prep``): its row panel of A (values
-    and stored-entry pattern, ``wa`` blocks) and its B K-slab (``wb``).
-    The slab rotates around the ring while each stage's products add in
-    place into the panel's value and count accumulators (``wm`` blocks).
-    Blocks are stored lane-dense (``block_layout``), so a device holds
-    eight arrays of ``wa``, ``wb`` or ``wm`` blocks of ``bs * bs`` words:
-    A's two, B's two and the two in flight, and the accumulators.
+    their block coordinates, ``_ring_prep``): its row panel of A (``wa``
+    blocks) and its B K-slab (``wb``), each stacked ``(w, 2, rows,
+    lanes)``: plane 0 the values, plane 1 the stored-entry pattern.  The
+    slab rotates around the ring, one ``ppermute`` a stage, while each
+    stage's worklist is walked once and its products add in place into
+    the panel's stacked accumulator (``wm`` blocks: plane 0 values, plane
+    1 structural counts).  Blocks are stored lane-dense
+    (``block_layout``), so a device holds eight planes of ``wa``, ``wb``
+    or ``wm`` blocks of ``bs * bs`` words: A's two, B's two and the two
+    in flight, and the accumulator's two.
 
     The mask-aligned extraction runs inside the shard program: every mask
     element lives in exactly one row panel, so each device scatters its
@@ -270,42 +262,41 @@ def _ring_sparse_program(mesh: Mesh, axis: str, p: int, bs: int, wa: int,
                                         interpret=interpret)
 
     def blocks(n, at, values):
-        return jnp.zeros((n, rows, lanes), jnp.float32).at[
-            at[0], at[1], at[2]].set(values, mode="drop")
+        """Values in plane 0, 1.0 at every stored entry in plane 1."""
+        return jnp.zeros((n, 2, rows, lanes), jnp.float32).at[
+            at[0], :, at[1], at[2]].set(
+                jnp.stack([values, jnp.ones_like(values)], axis=1),
+                mode="drop")
 
     def local(a_at, a_vals, b_at, b_vals, sched, ex):
         a_at, a_vals, b_at, b_vals, sched, ex = (
             x[0] for x in (a_at, a_vals, b_at, b_vals, sched, ex))
-        av, ap = blocks(wa, a_at, a_vals), blocks(wa, a_at, 1.0)
-        bv, bp = blocks(wb, b_at, b_vals), blocks(wb, b_at, 1.0)
+        a, b = blocks(wa, a_at, a_vals), blocks(wb, b_at, b_vals)
         ring = [(i, (i + 1) % p) for i in range(p)]
 
-        def compute(s, vals, cnts, bv, bp):
+        def compute(s, acc, b):
             chunks = jax.lax.dynamic_index_in_dim(sched, s, 0, keepdims=False)
-            return (apply_stage(vals, av, bv, chunks),
-                    apply_stage(cnts, ap, bp, chunks))
+            return apply_stage(acc, a, b, chunks)
 
         def stage(s, carry):
-            vals, cnts, bv, bp = carry
+            acc, b = carry
             # send the slab on first -> XLA overlaps the collective with
             # this stage's block products
-            nxt = tuple(jax.lax.ppermute(x, axis, ring) for x in (bv, bp))
-            return compute(s, vals, cnts, bv, bp) + nxt
+            nxt = jax.lax.ppermute(b, axis, ring)
+            return compute(s, acc, b), nxt
 
-        vals = jnp.zeros((wm, rows, lanes), jnp.float32)
-        cnts = jnp.zeros((wm, rows, lanes), jnp.float32)
+        acc = jnp.zeros((wm, 2, rows, lanes), jnp.float32)
         # the last stage is peeled: its prefetched slab would be dropped,
         # so only p-1 slab rotations are ever transmitted
-        vals, cnts, bv, bp = jax.lax.fori_loop(0, p - 1, stage,
-                                               (vals, cnts, bv, bp))
-        vals, cnts = compute(p - 1, vals, cnts, bv, bp)
+        acc, b = jax.lax.fori_loop(0, p - 1, stage, (acc, b))
+        acc = compute(p - 1, acc, b)
         # panel-local extraction (padding entries carry rowl == rows_loc,
         # dropped by the out-of-bounds scatter mode)
         loc, r, c, rowl, slot = (ex[i] for i in range(5))
         out_v = jnp.zeros((rows_loc, pm), jnp.float32).at[rowl, slot].set(
-            vals[loc, r, c], mode="drop")
+            acc[loc, 0, r, c], mode="drop")
         out_p = jnp.zeros((rows_loc, pm), bool).at[rowl, slot].set(
-            cnts[loc, r, c] > 0, mode="drop")
+            acc[loc, 1, r, c] > 0, mode="drop")
         # row-sharded over the axis: (p * rows_loc, pm), panel by panel
         return out_v, out_p
 
@@ -440,9 +431,13 @@ def _ring_prep(A: CSR, B: CSR, M: CSR, bs: int, p: int,
             wa=max(1, int(a_counts.max())), wb=max(1, int(b_counts.max())),
             wm=max(1, int(m_counts.max())), mask_cols=M_p.cols,
             pm=M_p.width, on_mesh={})
+        # grid steps the busiest chip launches a solve, each over both
+        # planes (values, pattern) of the program's stacked blocks
+        launched = (sched[:, :, :, 3, :] != 0).any(axis=-1).sum(axis=(1, 2))
         sp.set(blocks=a_counts.tolist(), slab_blocks=b_counts.tolist(),
                entries=entries.sum(axis=1).tolist(),
-               stage_entries=entries.max(axis=0).tolist())
+               stage_entries=entries.max(axis=0).tolist(), planes=2,
+               steps=int(launched.max()) * sched.shape[-1])
     _ring_prep_cache.put(key, prep)  # lint: plan-key-ok(structure-pure prep)
     return prep
 

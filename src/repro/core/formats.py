@@ -420,7 +420,8 @@ def padded_from_dense(a: np.ndarray, width: Optional[int] = None) -> PaddedCSR:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class BCSR:
-    """Block-CSR: indptr:(Mb+1,), indices:(nnzb,), blocks:(nnzb, bs, bs).
+    """Block-CSR: indptr:(Mb+1,), indices:(nnzb,), blocks:(nnzb, bs, bs),
+    or the block kernel's stacked operand (``bcsr_from_csr(pattern=True)``).
 
     ``indptr``/``indices`` live on host (numpy) because they drive schedule
     construction (the symbolic phase); ``blocks`` is a device array.
@@ -490,7 +491,8 @@ def bcsr_from_dense(a: np.ndarray, block_size: int, prune_zero: bool = True) -> 
     return BCSR(indptr, cols.astype(np.int64), jnp.asarray(blocks), (m, n), bs)
 
 
-def bcsr_from_csr(a: CSR, block_size: int, dtype=None) -> BCSR:
+def bcsr_from_csr(a: CSR, block_size: int, dtype=None,
+                  pattern: bool = False) -> BCSR:
     """Direct CSR -> BCSR: scatter entries into only the occupied blocks.
 
     Never materializes the dense matrix — memory is O(nnzb * bs^2), bounded
@@ -499,6 +501,14 @@ def bcsr_from_csr(a: CSR, block_size: int, dtype=None) -> BCSR:
     last full block are padded into partial edge blocks (zero filled), same
     layout as ``bcsr_from_dense``.  Assumes ``a`` has no duplicate entries
     (every ``csr_from_coo``-built CSR satisfies this).
+
+    With ``pattern`` the blocks are the block kernel's stacked operand,
+    ``(nnzb, 2, rows, lanes)`` with each block stored as
+    ``kernel.block_layout(bs)`` says (lane-dense for bs 32 and 64, so the
+    kernel reads them without a padded copy): plane 0 the values, plane 1
+    1.0 at every stored entry (an explicitly stored 0.0 included), both
+    scattered into one host array and copied to the device once
+    (``ops.block_spgemm_with_structure``).
     """
     bs = block_size
     m, n = a.shape
@@ -509,8 +519,18 @@ def bcsr_from_csr(a: CSR, block_size: int, dtype=None) -> BCSR:
         key = (rows // bs) * nb + cols // bs
         uniq, inv = np.unique(key, return_inverse=True)
         sp.set(nnzb=len(uniq))
-        blocks = np.zeros((len(uniq), bs, bs), dtype=a.data.dtype)
-        blocks[inv, rows % bs, cols % bs] = a.data
+        r, c = rows % bs, cols % bs
+        if pattern:
+            from repro.kernels.masked_matmul.kernel import (block_layout,
+                                                            block_position)
+            blocks = np.zeros((len(uniq), 2) + block_layout(bs),
+                              dtype=a.data.dtype)
+            r, c = block_position(r, c, bs)
+            blocks[inv, 0, r, c] = a.data
+            blocks[inv, 1, r, c] = 1
+        else:
+            blocks = np.zeros((len(uniq), bs, bs), dtype=a.data.dtype)
+            blocks[inv, r, c] = a.data
         ubr, ubc = uniq // nb, uniq % nb
         indptr = np.zeros(mb + 1, dtype=np.int64)
         np.add.at(indptr, ubr + 1, 1)

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.kernels.masked_matmul.kernel import block_position
 
 from . import accumulators as acc
 from .formats import (CSR, PaddedCSR, padded_from_csr, csr_from_coo,
@@ -248,12 +249,13 @@ def symbolic_phase(A: PaddedCSR, M: PaddedCSR, B: Optional[PaddedCSR], *,
 
 
 @functools.partial(jax.jit, static_argnames=("m", "pm"))
-def _tile_gather(c_blocks, s_blocks, pos, roff, coff, rows, slots, *, m, pm):
-    """Gather per-mask-element values/structure out of the block result and
-    scatter them into the mask-aligned (m, pm) layout."""
-    vals_flat = c_blocks[pos, roff, coff]
-    cnt_flat = s_blocks[pos, roff, coff]
-    vals = jnp.zeros((m, pm), c_blocks.dtype)
+def _tile_gather(blocks, pos, roff, coff, rows, slots, *, m, pm):
+    """Gather per-mask-element values (plane 0) and structural counts
+    (plane 1) out of the stacked block result and scatter them into the
+    mask-aligned (m, pm) layout."""
+    vals_flat = blocks[pos, 0, roff, coff]
+    cnt_flat = blocks[pos, 1, roff, coff]
+    vals = jnp.zeros((m, pm), blocks.dtype)
     vals = vals.at[rows, slots].set(vals_flat, mode="drop")
     present = jnp.zeros((m, pm), bool)
     present = present.at[rows, slots].set(cnt_flat > 0, mode="drop")
@@ -269,10 +271,12 @@ def _masked_spgemm_tile(A: CSR, B: CSR, M: CSR, *,
     Densify-free end to end: CSR operands scatter into occupied blocks
     (``bcsr_from_csr``), the vectorized host schedule replays on the block
     executor, and the result is gathered straight from the output blocks
-    into the same mask-aligned layout the row kernels produce.  ``present``
-    comes from a structural counting replay of the same schedule, so it is
-    exact element-level structure — bitwise the row kernels' semantics,
-    including numeric-cancellation cases.
+    into the same mask-aligned layout the row kernels produce.  Each
+    operand's values and stored-entry pattern are stacked as two planes of
+    one block array, so one walk of the schedule computes the values and
+    the structural counts ``present`` comes from: exact element-level
+    structure — bitwise the row kernels' semantics, including
+    numeric-cancellation cases and explicitly stored zeros.
     """
     from repro.kernels.masked_matmul.ops import (block_spgemm_with_structure,
                                                  resolve_executor)
@@ -292,30 +296,21 @@ def _masked_spgemm_tile(A: CSR, B: CSR, M: CSR, *,
     with obs.span("spgemm.tile", block=bs, m=m, n=n, executor=backend,
                   interpret=interpret):
         with obs.span("spgemm.host_prep", algorithm="tile"):
-            Ab = bcsr_from_csr(A, bs)
-            Bb = bcsr_from_csr(B, bs)
+            Ab = bcsr_from_csr(A, bs, pattern=True)
+            Bb = bcsr_from_csr(B, bs, pattern=True)
             Mb = bcsr_from_csr(M, bs)
-
-            def pattern(x: CSR):
-                """Stored-entry pattern blocks: 1.0 per CSR entry (an
-                explicitly stored 0.0 is structural to the row kernels)."""
-                ones = CSR(x.indptr, x.indices,
-                           np.ones(x.nnz, np.float32), x.shape)
-                return bcsr_from_csr(ones, bs).blocks
-
-            a_pat, b_pat = pattern(A), pattern(B)
-        Cb, Sb = block_spgemm_with_structure(
-            Ab, Bb, Mb, a_pattern=a_pat, b_pattern=b_pat,
-            interpret=interpret, backend=backend)
-        return gather_mask_aligned(M, Mb, Cb.blocks, Sb.blocks, n=n, wm=wm)
+        Cb = block_spgemm_with_structure(Ab, Bb, Mb, interpret=interpret,
+                                         backend=backend)
+        return gather_mask_aligned(M, Mb, Cb.blocks, n=n, wm=wm)
 
 
-def gather_mask_aligned(M: CSR, Mb_struct, c_blocks, s_blocks, *, n: int,
+def gather_mask_aligned(M: CSR, Mb_struct, blocks, *, n: int,
                         wm: Optional[int] = None) -> MaskedSpGEMMResult:
     """Extract a mask-aligned result from block-granular values/counts.
 
-    ``c_blocks``/``s_blocks`` are ``(nnzb, bs, bs)`` device arrays laid out
-    in ``Mb_struct``'s block order (the 1P allocation: output structure ==
+    ``blocks`` is a ``(nnzb, 2, *block_layout(bs))`` device array, values
+    in plane 0 and structural counts in plane 1, laid out in
+    ``Mb_struct``'s block order (the 1P allocation: output structure ==
     mask block structure).  The distributed ring does NOT come through
     here — its extraction is panel-local inside the shard program.
     """
@@ -329,9 +324,9 @@ def gather_mask_aligned(M: CSR, Mb_struct, c_blocks, s_blocks, *, n: int,
         mc = M.indices
         pos = bcsr_block_positions(Mb_struct, mr // bs, mc // bs)
         slots = np.arange(M.nnz, dtype=np.int64) - M.indptr[mr]
-        addr = [to_device(x) for x in (pos, mr % bs, mc % bs, mr, slots)]
-    vals, present = _tile_gather(c_blocks, s_blocks, *addr, m=m,
-                                 pm=M_p.width)
+        roff, coff = block_position(mr % bs, mc % bs, bs)
+        addr = [to_device(x) for x in (pos, roff, coff, mr, slots)]
+    vals, present = _tile_gather(blocks, *addr, m=m, pm=M_p.width)
     return MaskedSpGEMMResult(vals, present, M_p.cols, (m, n))
 
 

@@ -12,7 +12,12 @@ Two kernels:
 
 * ``block_spgemm_kernel`` — BCSR x BCSR masked product replaying a host-built
   worklist (the paper's Heap merge performed once at schedule-construction
-  time, §6's symbolic phase made free by the mask bound).
+  time, §6's symbolic phase made free by the mask bound).  Its operands may
+  carry a small plane axis, ``(nnzb, g, *block)``: one walk of the worklist
+  multiplies every plane of an A block by the same plane of its B block
+  (the tile route and the ring stack values in plane 0 and the stored-entry
+  pattern in plane 1), so each grid step's fixed cost is paid once for all
+  ``g`` products.
 
 TPU notes: the grid is executed sequentially per core, so accumulating into
 the same output block across consecutive grid steps (out index_map revisits)
@@ -132,38 +137,47 @@ def _block_spgemm_body(rank_ref, pa_ref, pb_ref, flags_ref,
     first = flags_ref[w] & 1
     real = (flags_ref[w] >> 1) & 1
     last = (flags_ref[w] >> 2) & 1
+    planes = acc_ref.shape[0]
 
     @pl.when(first == 1)
     def _zero():
         if accumulate:
-            acc_ref[...] = unpack_blocks(carried_ref[0], bs)
+            for q in range(planes):
+                acc_ref[q] = unpack_blocks(carried_ref[0, q], bs)
         else:
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(real == 1)
     def _mac():
-        acc_ref[...] += jnp.dot(unpack_blocks(a_ref[0], bs),
-                                unpack_blocks(b_ref[0], bs),
-                                preferred_element_type=jnp.float32)
+        for q in range(planes):
+            acc_ref[q] += jnp.dot(unpack_blocks(a_ref[0, q], bs),
+                                  unpack_blocks(b_ref[0, q], bs),
+                                  preferred_element_type=jnp.float32)
 
     @pl.when(last == 1)
     def _flush():
-        o_ref[...] = pack_blocks(acc_ref[...], o_ref.shape[1]).astype(
-            o_ref.dtype)[None]
+        for q in range(planes):
+            o_ref[0, q] = pack_blocks(acc_ref[q], o_ref.shape[2]).astype(
+                o_ref.dtype)
 
 
 def block_spgemm_kernel(a_blocks, b_blocks, rank, pa, pb, flags, out,
                         *, bs, interpret=False, accumulate=False):
     """Masked BCSR product from one worklist chunk, replayed into ``out``.
 
-    a_blocks: (nnzb_a, *block); b_blocks: (nnzb_b, *block); out:
-    (nnzb_out, *block) f32, where ``block`` is ``(bs, bs)`` or the
+    a_blocks: (nnzb_a, g, *block); b_blocks: (nnzb_b, g, *block); out:
+    (nnzb_out, g, *block) f32, where ``block`` is ``(bs, bs)`` or the
     lane-dense ``block_layout(bs)`` (the kernel unpacks each block it
-    reads and packs each it writes).
+    reads and packs each it writes).  ``g`` is the plane axis: one grid
+    step reads an A block's ``g`` planes and a B block's as one block
+    each and adds ``a[q] @ b[q]`` into plane ``q`` of the rank's
+    accumulator, for every ``q``.  Operands without the axis,
+    ``(nnzb, *block)``, are one plane, and so is the result.
     rank/pa/pb: (W,) int32 — output block rank and A/B block positions.
     flags: (W,) int32 bitfield — 1=first visit of rank, 2=real product
       (0 -> zero-fill entry for a mask block with no contribution),
-      4=last visit of rank (flush accumulator to HBM).
+      4=last visit of rank (flush accumulator to HBM); each holds for
+      every plane alike.
     The worklist MUST be sorted by rank (sequential-grid accumulation) and
     must not end inside a rank.  ``out`` is aliased to the result: blocks
     the chunk does not visit keep their values, which is what lets
@@ -172,22 +186,27 @@ def block_spgemm_kernel(a_blocks, b_blocks, rank, pa, pb, flags, out,
     first visit starts from its block in ``out`` instead of zero, so a
     replay adds into ``out`` in place.
     """
+    if out.ndim == 3:
+        return block_spgemm_kernel(
+            a_blocks[:, None], b_blocks[:, None], rank, pa, pb, flags,
+            out[:, None], bs=bs, interpret=interpret,
+            accumulate=accumulate)[:, 0]
     W = rank.shape[0]
     spec = functools.partial(pl.BlockSpec, (1,) + tuple(out.shape[1:]))
-    at_rank = spec(lambda w, r_r, pa_r, pb_r, f_r: (r_r[w], 0, 0))
+    at_rank = spec(lambda w, r_r, pa_r, pb_r, f_r: (r_r[w], 0, 0, 0))
     return pl.pallas_call(
         functools.partial(_block_spgemm_body, bs=bs, accumulate=accumulate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(W,),
             in_specs=[
-                spec(lambda w, r_r, pa_r, pb_r, f_r: (pa_r[w], 0, 0)),
-                spec(lambda w, r_r, pa_r, pb_r, f_r: (pb_r[w], 0, 0)),
+                spec(lambda w, r_r, pa_r, pb_r, f_r: (pa_r[w], 0, 0, 0)),
+                spec(lambda w, r_r, pa_r, pb_r, f_r: (pb_r[w], 0, 0, 0)),
                 # ``out``: read only to accumulate
                 at_rank if accumulate else pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=at_rank,
-            scratch_shapes=[pltpu.VMEM((bs, bs), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((out.shape[1], bs, bs), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(out.shape, out.dtype),
         # operand 6 = ``out`` (the four scalar-prefetch arrays count)

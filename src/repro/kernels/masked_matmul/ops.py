@@ -27,6 +27,7 @@ scalar-prefetch arrays can hold in SMEM with room to spare.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -35,7 +36,8 @@ import numpy as np
 
 from repro import obs
 from repro.core.formats import BCSR, to_device
-from .kernel import masked_matmul_kernel, block_spgemm_kernel
+from .kernel import (block_spgemm_kernel, masked_matmul_kernel, pack_blocks,
+                     unpack_blocks)
 
 Schedule = Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]
 
@@ -332,7 +334,12 @@ def replay_chunks(out, a_blocks, b_blocks, chunks, *, bs, interpret,
     visits gain the products instead of being replaced by them).  Chunks
     whose flags are all off (the ring's stage padding) are never
     launched: a Pallas output block is written back on every visit, even
-    one whose body never stored."""
+    one whose body never stored.
+
+    Operands and ``out`` may hold a plane axis, ``(nnzb, g, *block)``
+    (``block_spgemm_kernel``): the worklist is walked once and every grid
+    step multiplies all ``g`` planes, so ``g`` products cost the steps of
+    one."""
     n_real = jnp.sum(jnp.any(chunks[:, 3, :] != 0, axis=1))
 
     def body(c, out):
@@ -348,22 +355,25 @@ def replay_chunks(out, a_blocks, b_blocks, chunks, *, bs, interpret,
                    static_argnames=("nnzb_out", "bs", "interpret"))
 def _block_spgemm_pallas(a_blocks, b_blocks, chunks, *, nnzb_out, bs,
                          interpret):
-    out = jnp.zeros((nnzb_out, bs, bs), jnp.float32)
+    out = jnp.zeros((nnzb_out,) + a_blocks.shape[1:], jnp.float32)
     return replay_chunks(out, a_blocks, b_blocks, chunks, bs=bs,
                          interpret=interpret)
 
 
-@jax.jit
-def _xla_chunk_add(out, a_blocks, b_blocks, rank, pa, pb, flags):
+@functools.partial(jax.jit, static_argnames=("bs",))
+def _xla_chunk_add(out, a_blocks, b_blocks, rank, pa, pb, flags, *, bs):
     """One worklist chunk: gather, batched matmul, segment-add into ``out``.
-    Zero-fill entries (real-bit off) gather block 0 but contribute
-    nothing."""
+    Blocks are stored as the Pallas kernel stores them (``(bs, bs)`` or
+    lane-dense, with or without a plane axis) and multiplied plane by
+    plane.  Zero-fill entries (real-bit off) gather block 0 but
+    contribute nothing."""
     real = ((flags >> 1) & 1).astype(jnp.float32)
-    prods = jnp.einsum("wij,wjk->wik",
-                       a_blocks[pa].astype(jnp.float32),
-                       b_blocks[pb].astype(jnp.float32),
+    prods = jnp.einsum("w...ij,w...jk->w...ik",
+                       unpack_blocks(a_blocks[pa], bs).astype(jnp.float32),
+                       unpack_blocks(b_blocks[pb], bs).astype(jnp.float32),
                        preferred_element_type=jnp.float32)
-    return out.at[rank].add(prods * real[:, None, None])
+    prods = prods * real.reshape((-1,) + (1,) * (prods.ndim - 1))
+    return out.at[rank].add(pack_blocks(prods, out.shape[-2]))
 
 
 #: peak f32 elements the XLA executor materializes per worklist chunk
@@ -373,20 +383,21 @@ def _xla_chunk_add(out, a_blocks, b_blocks, rank, pa, pb, flags):
 _XLA_CHUNK_ELEMS = 1 << 24
 
 
-def _block_spgemm_xla(a_blocks, b_blocks, rank, pa, pb, flags, *,
-                      nnzb_out, bs):
-    """XLA replay of the worklist, chunked to bound peak memory.
+def block_spgemm_xla(out, a_blocks, b_blocks, rank, pa, pb, flags, *, bs):
+    """XLA replay of a worklist, adding its products into ``out``, in
+    chunks to bound peak memory.
 
     Chunks are independent partial sums into the same output (the rank
     segment-add is associative), so first/last flags are irrelevant here —
-    only the real-bit is consulted.  The tail chunk is padded with
-    real-bit-off entries to keep exactly one compiled chunk shape.
+    only the real-bit is consulted, and padding entries (real-bit off)
+    may sit anywhere.  The tail chunk is padded with real-bit-off entries
+    to keep exactly one compiled chunk shape.
     """
     W = int(rank.shape[0])
-    chunk = max(1, _XLA_CHUNK_ELEMS // (bs * bs))
-    out = jnp.zeros((nnzb_out, bs, bs), jnp.float32)
+    chunk = max(1, _XLA_CHUNK_ELEMS // math.prod(a_blocks.shape[1:]))
     if W <= chunk:
-        return _xla_chunk_add(out, a_blocks, b_blocks, rank, pa, pb, flags)
+        return _xla_chunk_add(out, a_blocks, b_blocks, rank, pa, pb, flags,
+                              bs=bs)
     pad = -W % chunk
     if pad:
         z = jnp.zeros(pad, rank.dtype)
@@ -395,7 +406,7 @@ def _block_spgemm_xla(a_blocks, b_blocks, rank, pa, pb, flags, *,
     for s in range(0, W + pad, chunk):
         e = s + chunk
         out = _xla_chunk_add(out, a_blocks, b_blocks, rank[s:e], pa[s:e],
-                             pb[s:e], flags[s:e])
+                             pb[s:e], flags[s:e], bs=bs)
     return out
 
 
@@ -427,20 +438,24 @@ def _run_schedule(A: BCSR, B: BCSR, M: BCSR, schedule: Schedule,
     # an empty operand leaves only zero-fill entries in the worklist, but
     # those still address block 0 — give them one zero block to read
     if blocks_a.shape[0] == 0:
-        blocks_a = jnp.zeros((1, bs, bs), blocks_a.dtype)
+        blocks_a = jnp.zeros((1,) + blocks_a.shape[1:], blocks_a.dtype)
     if blocks_b.shape[0] == 0:
-        blocks_b = jnp.zeros((1, bs, bs), blocks_b.dtype)
+        blocks_b = jnp.zeros((1,) + blocks_b.shape[1:], blocks_b.dtype)
     if backend == "pallas":
         with obs.span("spgemm.chunk") as sp:
             chunks = chunk_schedule(schedule,
                                     min(SPGEMM_CHUNK, len(schedule[0])))
-            sp.set(chunks=len(chunks))
+            # grid steps the replay launches, each over every plane
+            sp.set(chunks=len(chunks),
+                   planes=blocks_a.shape[1] if blocks_a.ndim == 4 else 1,
+                   steps=chunks.shape[0] * chunks.shape[2])
         return _block_spgemm_pallas(blocks_a, blocks_b, to_device(chunks),
                                     nnzb_out=M.nnzb, bs=bs,
                                     interpret=interpret)
     rank, pa, pb, flags = (to_device(x) for x in schedule)
-    return _block_spgemm_xla(blocks_a, blocks_b, rank, pa, pb, flags,
-                             nnzb_out=M.nnzb, bs=bs)
+    out = jnp.zeros((M.nnzb,) + blocks_a.shape[1:], jnp.float32)
+    return block_spgemm_xla(out, blocks_a, blocks_b, rank, pa, pb, flags,
+                            bs=bs)
 
 
 def block_spgemm(A: BCSR, B: BCSR, M: BCSR, *, interpret=None,
@@ -453,7 +468,7 @@ def block_spgemm(A: BCSR, B: BCSR, M: BCSR, *, interpret=None,
     An all-empty mask is a defined degenerate case: the worklist is empty
     and an empty BCSR is returned without launching a kernel.  Pass a
     precomputed ``schedule`` to amortize the symbolic phase across several
-    numeric replays (e.g. a values pass and a structure pass).
+    numeric replays of one structure.
     """
     assert A.block_size == B.block_size == M.block_size
     bs = A.block_size
@@ -470,40 +485,32 @@ def block_spgemm(A: BCSR, B: BCSR, M: BCSR, *, interpret=None,
 
 
 def block_spgemm_with_structure(A: BCSR, B: BCSR, M: BCSR, *,
-                                a_pattern=None, b_pattern=None,
                                 interpret=None,
-                                backend: Optional[str] = None
-                                ) -> Tuple[BCSR, BCSR]:
-    """(values, structural-counts) pair sharing ONE schedule build.
+                                backend: Optional[str] = None) -> BCSR:
+    """Values and structural counts of C = M (.) (A B) from ONE walk of
+    one schedule.
 
-    The second BCSR replays the same worklist over the operands' 0/1
-    patterns; its entries count structural contributions, so ``count > 0``
-    is exact element-level presence — identical to the row kernels'
-    structural semantics even when numeric cancellation produces a stored
-    0.0 in the values pass.  ``a_pattern``/``b_pattern`` are optional
-    (nnzb, bs, bs) 0/1 block arrays marking the operands' *stored entries*
-    (the row kernels treat an explicitly stored 0.0 as structural); when
-    omitted, value-nonzeroness of the blocks is used, which cannot tell a
-    stored zero from block padding.
+    ``A.blocks`` and ``B.blocks`` are stacked ``(nnzb, 2, rows, lanes)``,
+    blocks stored as ``kernel.block_layout(bs)`` says: plane 0 the values,
+    plane 1 the 0/1 pattern of the operand's *stored entries*
+    (``bcsr_from_csr(..., pattern=True)``; the row kernels treat an
+    explicitly stored 0.0 as structural).  The result's blocks are stacked
+    and stored the same way: plane 0 the values, plane 1 the count of
+    structural contributions, so ``count > 0`` is exact element-level
+    presence, identical to the row kernels' structural semantics even
+    when numeric cancellation leaves a stored 0.0 in plane 0.
     """
     assert A.block_size == B.block_size == M.block_size
     bs = A.block_size
     shape = (M.shape[0], B.shape[1])
     if M.nnzb == 0:
-        empty = jnp.zeros((0, bs, bs), jnp.float32)
-        return (BCSR(M.indptr.copy(), M.indices.copy(), empty, shape, bs),
-                BCSR(M.indptr.copy(), M.indices.copy(), empty, shape, bs))
+        return BCSR(M.indptr.copy(), M.indices.copy(),
+                    jnp.zeros((0,) + A.blocks.shape[1:], jnp.float32),
+                    shape, bs)
     schedule = build_spgemm_schedule(A, B, M)
-    vals = _run_schedule(A, B, M, schedule, A.blocks, B.blocks,
-                         interpret=interpret, backend=backend)
-    if a_pattern is None:
-        a_pattern = (A.blocks != 0).astype(jnp.float32)
-    if b_pattern is None:
-        b_pattern = (B.blocks != 0).astype(jnp.float32)
-    struct = _run_schedule(A, B, M, schedule, a_pattern, b_pattern,
+    blocks = _run_schedule(A, B, M, schedule, A.blocks, B.blocks,
                            interpret=interpret, backend=backend)
-    return (BCSR(M.indptr.copy(), M.indices.copy(), vals, shape, bs),
-            BCSR(M.indptr.copy(), M.indices.copy(), struct, shape, bs))
+    return BCSR(M.indptr.copy(), M.indices.copy(), blocks, shape, bs)
 
 
 def block_spgemm_from_csr(A, B, M, *, block_size: int, interpret=None,

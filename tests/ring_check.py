@@ -103,8 +103,45 @@ def indivisible_block_rows():
     print("indivisible_block_rows OK")
 
 
+def signed_with_stored_zeros(rng, n, density):
+    """CSR with values in {-1, 0, 1, 2}, every 0 explicitly stored."""
+    from repro.core.formats import csr_from_coo
+    rows, cols = np.nonzero(rng.random((n, n)) < density)
+    vals = rng.integers(-1, 3, len(rows)).astype(np.float32)
+    return csr_from_coo(rows, cols, vals, (n, n), sum_dups=False)
+
+
+def stored_zeros_and_cancellation():
+    """Stored zeros and cancelling pairs: the ring's values and presence
+    equal the row kernels', on the XLA executor and on the Pallas one
+    (interpret mode), whose prep span reports one walk over both planes
+    and the busiest device's grid steps in whole chunks."""
+    from repro.core.distributed import clear_ring_prep_cache
+    rng = np.random.default_rng(31)
+    A, B, M = (signed_with_stored_zeros(rng, 96, d) for d in (0.1, 0.1, 0.3))
+    want = masked_spgemm(A, B, M, algorithm="msa")
+    present = np.asarray(want.present)
+    assert (present & (np.asarray(want.vals) == 0)).any(), "no cancellation"
+    clear_ring_prep_cache()
+    with obs.tracing() as tr:
+        pallas = ring_sparse_masked_spgemm(A, B, M, device_mesh(4),
+                                           block_size=8, backend="pallas",
+                                           interpret=True)
+    xla = masked_spgemm(A, B, M, algorithm="ring", tile_block=8, devices=4)
+    for got in (pallas, xla):
+        assert np.array_equal(np.asarray(got.present), present)
+        assert np.array_equal(np.asarray(got.vals), np.asarray(want.vals))
+    (prep,) = [s["attrs"] for s in tr.sink.spans()
+               if s["name"] == "spgemm.ring_prep"]
+    length = max(prep["stage_entries"])     # under one chunk a stage
+    assert prep["planes"] == 2 and prep["steps"] % length == 0, prep
+    assert max(prep["entries"]) <= prep["steps"] <= 4 * length, prep
+    print("stored_zeros_and_cancellation OK", prep["steps"])
+
+
 CHECKS = {f.__name__: f for f in (exact_counts, every_device_holds_blocks,
-                                  indivisible_block_rows)}
+                                  indivisible_block_rows,
+                                  stored_zeros_and_cancellation)}
 
 if __name__ == "__main__":
     assert jax.device_count() == 4, jax.devices()

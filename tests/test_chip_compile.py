@@ -73,6 +73,22 @@ def test_block_spgemm_compiles_past_one_call_of_smem(one_chip, bs):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("bs", [8, 32])
+def test_stacked_block_spgemm_compiles(one_chip, bs):
+    """The tile route's operands, values and pattern stacked as two planes
+    of one ``(nnzb, 2, bs, bs)`` array: one walk of five chunks."""
+    from repro.kernels.masked_matmul.ops import (SPGEMM_CHUNK,
+                                                 _block_spgemm_pallas)
+    blocks = jax.ShapeDtypeStruct((4096, 2, bs, bs), jnp.float32,
+                                  sharding=one_chip)
+    chunks = jax.ShapeDtypeStruct((5, 4, SPGEMM_CHUNK), jnp.int32,
+                                  sharding=one_chip)
+    compiled = _block_spgemm_pallas.lower(
+        blocks, blocks, chunks, nnzb_out=4096, bs=bs,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def _ring_program(topo, *, blocks, entries, chunks, pm, rows_loc):
     """The sparse ring's shard program on four chips, Pallas stages
     included, compiled at the given per-device sizes: ``blocks`` of A's

@@ -111,3 +111,56 @@ def test_schedule_is_sorted_and_flagged():
     for r in range(Mb.nnzb):
         fs = flags[rank == r]
         assert fs[0] & 1 and fs[-1] & 4   # first/last flags per rank
+
+
+@pytest.mark.parametrize("bs", [8, 32])
+@pytest.mark.parametrize("accumulate", [False, True])
+def test_stacked_walk_equals_one_walk_per_plane(bs, accumulate):
+    """One walk over operands stacked ``(nnzb, 2, *block)`` equals two
+    walks over each plane alone, bitwise, and the products the worklist
+    names: bs 8 in the ``(bs, bs)`` layout, bs 32 lane-dense, with
+    zero-fill ranks and chunk tails whose flags are all off."""
+    import functools
+    import jax
+    from repro.kernels.masked_matmul.kernel import (block_layout,
+                                                    pack_blocks,
+                                                    unpack_blocks)
+    from repro.kernels.masked_matmul.ops import chunk_schedule, replay_chunks
+    rng = np.random.default_rng(bs + accumulate)
+    rows, lanes = block_layout(bs)
+    assert (rows == bs) == (bs == 8)
+
+    def structure(density):
+        occupied = rng.random((6, 6)) < density
+        return bcsr_from_dense(np.kron(occupied, np.ones((bs, bs),
+                                                         np.float32)), bs)
+
+    Ab, Bb, Mb = structure(0.4), structure(0.4), structure(0.6)
+    schedule = build_spgemm_schedule(Ab, Bb, Mb)
+    rank, pa, pb, flags = schedule
+    real = (flags >> 1) & 1 == 1
+    assert (~real).any(), "no zero-fill rank"
+    chunks = chunk_schedule(schedule, 16)
+    assert len(chunks) > 1 and (chunks[:, 3, :] == 0).any(), "no tail"
+
+    def ints(n):
+        return rng.integers(-3, 4, (n, 2, rows, lanes)).astype(np.float32)
+
+    a, b, out = ints(Ab.nnzb), ints(Bb.nnzb), ints(Mb.nnzb)
+    run = jax.jit(functools.partial(replay_chunks, bs=bs, interpret=True,
+                                    accumulate=accumulate))
+    both = np.asarray(run(out, a, b, jnp.asarray(chunks)))
+    assert both.shape == out.shape
+    for q in range(2):
+        one = np.asarray(run(out[:, q], a[:, q], b[:, q],
+                             jnp.asarray(chunks)))
+        np.testing.assert_array_equal(both[:, q], one)
+        # small integers: every summation order is exact in float32
+        want = (np.array(unpack_blocks(out[:, q], bs)) if accumulate
+                else np.zeros((Mb.nnzb, bs, bs), np.float32))
+        ua = np.asarray(unpack_blocks(a[:, q], bs))
+        ub = np.asarray(unpack_blocks(b[:, q], bs))
+        for r, i, j in zip(rank[real], pa[real], pb[real]):
+            want[r] += ua[i] @ ub[j]
+        np.testing.assert_array_equal(
+            one, np.asarray(pack_blocks(jnp.asarray(want), rows)))

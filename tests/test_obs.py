@@ -573,9 +573,10 @@ def _tile_solve(L, backend="pallas"):
 
 
 def test_tile_route_spans_name_its_host_work():
-    """One schedule build, one chunking per replay (values, structure),
-    five BCSR conversions (three operands, two patterns); host_prep still
-    holds exactly the BCSR conversions, and the rest sits beside it."""
+    """One schedule build, one chunking for the one walk that carries
+    values and structure (two planes), three BCSR conversions (A and B
+    with their pattern plane, the mask); host_prep still holds exactly the
+    BCSR conversions, and the rest sits beside it."""
     from repro.core.formats import bcsr_from_csr
     from repro.kernels.masked_matmul import ops
     L = _lower()
@@ -585,23 +586,24 @@ def test_tile_route_spans_name_its_host_work():
     names = [r["name"] for r in recs]
     assert (names.count("spgemm.schedule"), names.count("spgemm.chunk"),
             names.count("spgemm.bcsr"), names.count("spgemm.gather")) \
-        == (1, 2, 5, 1)
+        == (1, 1, 3, 1)
 
     def children(name):
         (parent,) = [r for r in recs if r["name"] == name]
         return sorted(r["name"] for r in recs
                       if r["parent"] == parent["span"])
 
-    assert children("spgemm.host_prep") == ["spgemm.bcsr"] * 5
+    assert children("spgemm.host_prep") == ["spgemm.bcsr"] * 3
     assert children("spgemm.tile") == sorted(
         ["spgemm.host_prep", "spgemm.schedule", "spgemm.chunk",
-         "spgemm.chunk", "spgemm.h2d", "spgemm.h2d", "spgemm.gather"])
+         "spgemm.h2d", "spgemm.gather"])
     Lb = bcsr_from_csr(L, 8)
     entries = len(ops.build_spgemm_schedule(Lb, Lb, Lb)[0])
     attrs = {r["name"]: r.get("attrs") for r in recs}
     assert attrs["spgemm.schedule"] == {"entries": entries}
     assert attrs["spgemm.bcsr"] == {"bs": 8, "nnzb": Lb.nnzb}
-    assert attrs["spgemm.chunk"] == {"chunks": 1}
+    assert attrs["spgemm.chunk"] == {"chunks": 1, "planes": 2,
+                                     "steps": entries}
 
 
 def _h2d_case(route, L):
@@ -621,7 +623,7 @@ def _h2d_case(route, L):
                         len(ops.build_spgemm_schedule(Lb, Lb, Lb)[0]))
         return (lambda: _tile_solve(L),
                 5 * Lb.nnzb * 8 * 8 * 4        # three operands, two patterns
-                + 2 * 4 * chunk_len * 4        # one chunk per replay
+                + 4 * chunk_len * 4            # one chunk, walked once
                 + padded(widest)               # the mask, padded
                 + 5 * L.nnz * 4)               # gather addressing
     if route == "ring":
@@ -760,7 +762,8 @@ def test_ring_prep_and_host_prep_spans():
     assert ring["attrs"] == {"p": 1, "bs": 8, "blocks": [Lb.nnzb],
                              "slab_blocks": [Lb.nnzb],
                              "entries": [products],
-                             "stage_entries": [products]}
+                             "stage_entries": [products], "planes": 2,
+                             "steps": products}
     # the schedule build is the prep's own, not a sibling of it
     (sched,) = [r for r in recs[0] if r["name"] == "spgemm.schedule"]
     assert sched["parent"] == ring["span"]

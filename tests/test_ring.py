@@ -21,7 +21,8 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 @pytest.mark.parametrize("check", ["exact_counts",
                                    "every_device_holds_blocks",
-                                   "indivisible_block_rows"])
+                                   "indivisible_block_rows",
+                                   "stored_zeros_and_cancellation"])
 def test_ring_on_four_devices(check):
     env = dict(os.environ)
     env["PYTHONPATH"] = str(ROOT / "src")

@@ -371,3 +371,49 @@ def test_tile_route_rejects_unsupported():
     with pytest.raises(NotImplementedError):
         masked_spgemm(Ac, Ac, Mc, algorithm="tile", tile_block=8,
                       complement=True)
+
+
+def signed_with_stored_zeros(rng, n, density):
+    """CSR with values in {-1, 0, 1, 2}, every 0 explicitly stored: the
+    products of two such matrices cancel in places."""
+    from repro.core.formats import csr_from_coo
+    rows, cols = np.nonzero(rng.random((n, n)) < density)
+    vals = rng.integers(-1, 3, len(rows)).astype(np.float32)
+    return csr_from_coo(rows, cols, vals, (n, n), sum_dups=False)
+
+
+@pytest.mark.parametrize("bs,chunk", [(8, None), (8, 8), (32, None)])
+def test_tile_route_walks_values_and_pattern_once(monkeypatch, bs, chunk):
+    """Stored zeros and cancelling pairs: the tile route's values and
+    presence equal the row kernels' on the Pallas executor, whose one
+    walk carries both planes (blocks stored ``(bs, bs)`` at bs 8,
+    lane-dense at bs 32); its ``spgemm.chunk`` span counts the grid steps
+    of that walk, the worklist rounded up to whole chunks."""
+    from repro import obs
+    from repro.core.masked_spgemm import _masked_spgemm_tile
+    rng = np.random.default_rng(29)
+    A, B, M = (signed_with_stored_zeros(rng, 40, d) for d in (0.2, 0.2, 0.4))
+    assert (A.data == 0).any() and (B.data == 0).any()
+    if chunk:
+        monkeypatch.setattr(ops, "SPGEMM_CHUNK", chunk)
+    with obs.tracing() as tr:
+        tile = _masked_spgemm_tile(A, B, M, block_size=bs, backend="pallas",
+                                   interpret=True)
+    msa = masked_spgemm(A, B, M, algorithm="msa")
+    present = np.asarray(msa.present)
+    assert (present & (np.asarray(msa.vals) == 0)).any(), "no cancellation"
+    np.testing.assert_array_equal(np.asarray(tile.present), present)
+    np.testing.assert_array_equal(np.asarray(tile.vals),
+                                  np.asarray(msa.vals))
+    (sp,) = [r["attrs"] for r in tr.sink.spans()
+             if r["name"] == "spgemm.chunk"]
+    schedule = build_spgemm_schedule(*(bcsr_from_csr(x, bs)
+                                       for x in (A, B, M)))
+    length = min(ops.SPGEMM_CHUNK, len(schedule[0]))
+    n_chunks = len(ops.chunk_schedule(schedule, length))
+    assert sp == {"chunks": n_chunks, "planes": 2,
+                  "steps": n_chunks * length}
+    if chunk is None:
+        assert sp["steps"] == len(schedule[0])
+    else:
+        assert n_chunks > 1 and sp["steps"] >= len(schedule[0])

@@ -4,7 +4,7 @@ Two measurements (structural, CPU container):
 1. masked tile kernels (interpret) vs jnp oracle — correctness + the tile
    worklist's flop saving vs a dense product (paper Fig. 1 at MXU scale).
 2. block_masked vs dense_masked attention: XLA-compiled flop counts from
-   cost_analysis — the saving the dry-run rooflines rely on.
+   cost_analysis.
 """
 from __future__ import annotations
 
@@ -15,8 +15,8 @@ import numpy as np
 from repro.core.formats import bcsr_from_dense
 from repro.kernels.masked_matmul.ops import block_spgemm, \
     build_spgemm_schedule
-from repro.models.attention import (block_masked_attention,
-                                    dense_masked_attention)
+from repro.kernels.flash_mask.attention import (block_masked_attention,
+                                                dense_masked_attention)
 from .common import save
 
 

@@ -3,9 +3,6 @@
   PYTHONPATH=src python -m benchmarks.run            # small CPU sizes
   PYTHONPATH=src python -m benchmarks.run --full     # larger suite
   PYTHONPATH=src python -m benchmarks.run --only density,triangle
-
-Roofline (needs results/dryrun from repro.launch.dryrun):
-  PYTHONPATH=src python -m benchmarks.roofline
 """
 from __future__ import annotations
 

@@ -32,10 +32,6 @@ one device.  The model's ``DIST_COST`` constants are per-backend
 calibration data: ``python -m repro.tune --only dist`` refits them from
 measured ring/row probes (forced host devices stand in for a real
 network, so refit on the actual mesh before trusting auto at scale).
-
-All device programs are pure ``shard_map``: they lower and compile for any
-mesh (including the 512-chip production mesh) and are exercised by the
-dry-run and the forced-multi-device CPU harness in ``tests/``.
 """
 from __future__ import annotations
 
@@ -444,10 +440,6 @@ def _ring_prep(A: CSR, B: CSR, M: CSR, bs: int, p: int,
 
 def clear_ring_prep_cache() -> None:
     _ring_prep_cache.clear()
-
-
-def ring_prep_cache_info() -> dict:
-    return _ring_prep_cache.info()
 
 
 def ring_sparse_masked_spgemm(A: CSR, B: CSR, M: CSR, mesh: Mesh, *,

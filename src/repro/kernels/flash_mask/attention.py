@@ -1,4 +1,4 @@
-"""Attention as Masked SpGEMM (paper technique inside the LM stack).
+"""Attention as Masked SpGEMM.
 
 ``scores = M (.) (Q Kᵀ)`` is a masked matrix product with a *structured*
 mask (causal / sliding-window / dense-prefix).  Three implementations:
@@ -9,12 +9,11 @@ mask (causal / sliding-window / dense-prefix).  Three implementations:
   expressed in XLA: a host-built tile worklist (only mask-admitted tiles),
   load-balanced by pairing long rows with short rows (folded-causal), then
   executed as a scan of uniform gather+matmul+streaming-softmax chunks.
-  The HLO flop count shows the saving (≈2x for causal, S/W for windows) —
-  this is what the dry-run rooflines measure.
-* Pallas runtime kernel (``repro.kernels.flash_mask``) — same worklist, VMEM
-  streaming, for real TPU execution.
+  The HLO flop count shows the saving (≈2x for causal, S/W for windows).
+* Pallas runtime kernel (``ops.flash_mask_attention``) — same worklist,
+  VMEM streaming, for real TPU execution.
 
-``decode_attention`` is the serve-time single-token path over a (possibly
+``decode_attention`` is the single-token path over a (possibly
 ring-buffered) KV cache.
 """
 from __future__ import annotations
@@ -27,13 +26,11 @@ import numpy as np
 
 from repro import caches
 
-from .common import pscan
-
 NEG_INF = -1e30
 
 
 # ---------------------------------------------------------------------------
-# parametric mask (shared with kernels/flash_mask)
+# parametric mask (``ref.mask_allowed`` has no bidirectional prefix)
 # ---------------------------------------------------------------------------
 
 
@@ -234,7 +231,7 @@ def block_masked_attention(q, k, v, *, causal=True, window=0, prefix=0,
             init = (jnp.full((2, bq_), NEG_INF, jnp.float32),
                     jnp.zeros((2, bq_), jnp.float32),
                     jnp.zeros((2, bq_, dv), jnp.float32))
-            (m_run, l_run, acc), _ = pscan(
+            (m_run, l_run, acc), _ = jax.lax.scan(
                 step, init, (kv_s, mem_s, val_s))
             # where-guarded denominator: with maximum(l, tiny), backward
             # computes 1/l^2 = inf (f32 overflow) and 0*inf = NaN for
@@ -272,7 +269,7 @@ def attention(q, k, v, *, impl="block_masked", causal=True, window=0,
                                       prefix=prefix, q_offset=q_offset,
                                       scale=scale, bq=block, bk=block)
     if impl == "flash_pallas":
-        from repro.kernels.flash_mask.ops import flash_mask_attention
+        from .ops import flash_mask_attention
         return flash_mask_attention(q, k, v, causal=causal, window=window,
                                     prefix=prefix, q_offset=q_offset,
                                     scale=scale, bq=block, bk=block)

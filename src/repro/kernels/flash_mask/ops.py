@@ -1,9 +1,8 @@
 """Public masked-attention op: schedule cache + batch/head vmap + GQA.
 
 ``flash_mask_attention`` is the runtime TPU path (Pallas; interpret=True on
-CPU).  The jnp fallbacks used for lowering/dry-run live in
-``repro.models.attention`` (they express the same block-skipping at XLA level
-so the roofline reflects the technique).
+CPU).  The XLA paths live in ``attention`` (they express the same
+block-skipping at XLA level).
 """
 from __future__ import annotations
 

@@ -86,28 +86,6 @@ def main():
     print("hlo OK")
 
 
-
-
-def moe_ep_check():
-    """EP shard_map MoE == dense path (capacity large enough: no drops)."""
-    import dataclasses
-    from repro.configs.base import get_config
-    from repro.models import layers as Lyr
-    cfg = get_config("moonshot_v1_16b_a3b", smoke=True)
-    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
-    params = Lyr.init_moe(jax.random.PRNGKey(0), cfg)
-    x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 16, 64)),
-                    jnp.float32) * 0.3
-    dense = Lyr._apply_moe_dense(params, cfg, x)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
-    with jax.set_mesh(mesh):
-        ep = jax.jit(lambda p, xx: Lyr.apply_moe(p, cfg, xx))(params, x)
-    np.testing.assert_allclose(np.asarray(ep), np.asarray(dense),
-                               rtol=2e-4, atol=2e-5)
-    print("moe_ep OK")
-
-
 if __name__ == "__main__":
     main()
-    moe_ep_check()
     print("DIST_ALL_OK")

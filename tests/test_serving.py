@@ -603,7 +603,7 @@ def test_schedule_memos_registered_in_caches():
     registry: cache_info() reports them and clear_all() empties them
     (the bounded-memory contract the cache-registry lint rule enforces)."""
     import repro.kernels.flash_mask.ops as _fops          # noqa: F401
-    import repro.models.attention as _attn                # noqa: F401
+    import repro.kernels.flash_mask.attention as _attn    # noqa: F401
 
     info = caches.cache_info()
     assert "flash-sched" in info

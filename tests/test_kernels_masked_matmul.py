@@ -113,6 +113,81 @@ def test_schedule_is_sorted_and_flagged():
         assert fs[0] & 1 and fs[-1] & 4   # first/last flags per rank
 
 
+def _oracle_schedule(A, B, M):
+    """The worklist by its definition, one mask block at a time: each k
+    ascending with A[i, k] and B[k, j] both present, else one zero-fill
+    entry."""
+    b_pos = {(k, int(B.indices[q])): q for k in range(B.block_rows)
+             for q in range(B.indptr[k], B.indptr[k + 1])}
+    rows = []
+    for i in range(M.block_rows):
+        for r in range(M.indptr[i], M.indptr[i + 1]):
+            j = int(M.indices[r])
+            hits = [(p, b_pos[int(A.indices[p]), j])
+                    for p in range(A.indptr[i], A.indptr[i + 1])
+                    if (int(A.indices[p]), j) in b_pos]
+            if not hits:
+                rows.append((r, 0, 0, 1 | 4))
+            for t, (p, q) in enumerate(hits):
+                rows.append((r, p, q,
+                             2 | (t == 0) | 4 * (t == len(hits) - 1)))
+    return tuple(np.array(c, np.int32) for c in zip(*rows))
+
+
+def _schedule_structures(case):
+    """Block occupancy grids ``(A, B, M)`` for a schedule case."""
+    rng = np.random.default_rng(len(case))
+    grid = lambda m, n, d: rng.random((m, n)) < d   # noqa: E731
+    if case == "rectangular":
+        return grid(5, 7, 0.6), grid(7, 4, 0.6), grid(5, 4, 0.7)
+    a, b, m = grid(6, 6, 0.6), grid(6, 6, 0.6), grid(6, 6, 0.6)
+    if case == "empty_b_column":
+        b[:, 2] = False
+        m[:, 2] = True
+    elif case == "empty_mask_row":
+        m[3] = False
+    elif case == "all_zero_fill":
+        # A's blocks lie inside every column range of B and never meet one
+        a[:] = False
+        a[:, [1, 2, 4]] = True
+        b[:] = False
+        b[[0, 3, 5]] = True
+        m[:] = True
+    return a, b, m
+
+
+@pytest.mark.parametrize("lookup", ["table", "search"])
+@pytest.mark.parametrize("case", ["square", "rectangular", "empty_b_column",
+                                  "empty_mask_row", "all_zero_fill"])
+def test_schedule_equals_per_mask_block_oracle(case, lookup):
+    """``build_spgemm_schedule`` equals the definition, bitwise, in all
+    four arrays.  The ``search`` variant embeds the same structures in a
+    grid with 60 more empty block rows and columns, so the dense table
+    would have more slots than there are candidates; the worklist and
+    its positions do not change."""
+    from repro import obs
+    bs = 2
+    pad = 0 if lookup == "table" else 60
+    a, b, m = (np.pad(x, ((0, pad), (0, pad)))
+               for x in _schedule_structures(case))
+    Ab, Bb, Mb = (bcsr_from_dense(np.kron(x, np.ones((bs, bs), np.float32)),
+                                  bs) for x in (a, b, m))
+    with obs.tracing() as tr:
+        got = build_spgemm_schedule(Ab, Bb, Mb)
+    want = _oracle_schedule(Ab, Bb, Mb)
+    for g, w in zip(got, want):
+        assert g.dtype == np.int32
+        np.testing.assert_array_equal(g, w)
+    (attrs,) = [r["attrs"] for r in tr.sink.spans()
+                if r["name"] == "spgemm.schedule"]
+    assert attrs["lookup"] == lookup
+    assert attrs["entries"] == len(want[0])
+    real = int(np.count_nonzero((want[3] >> 1) & 1))
+    assert real <= attrs["candidates"]
+    if case == "all_zero_fill":
+        assert real == 0 and attrs["candidates"] > 0
+
+
 @pytest.mark.parametrize("bs", [8, 32])
 @pytest.mark.parametrize("accumulate", [False, True])
 def test_stacked_walk_equals_one_walk_per_plane(bs, accumulate):

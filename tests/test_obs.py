@@ -598,9 +598,15 @@ def test_tile_route_spans_name_its_host_work():
         ["spgemm.host_prep", "spgemm.schedule", "spgemm.chunk",
          "spgemm.h2d", "spgemm.gather"])
     Lb = bcsr_from_csr(L, 8)
-    entries = len(ops.build_spgemm_schedule(Lb, Lb, Lb)[0])
+    flags = ops.build_spgemm_schedule(Lb, Lb, Lb)[3]
+    entries = len(flags)
+    zero_fill = int(np.count_nonzero((flags & 2) == 0))
     attrs = {r["name"]: r.get("attrs") for r in recs}
-    assert attrs["spgemm.schedule"] == {"entries": entries}
+    sched = attrs["spgemm.schedule"]
+    assert set(sched) == {"entries", "candidates", "lookup"}
+    assert sched["entries"] == entries
+    assert sched["candidates"] >= entries - zero_fill
+    assert sched["lookup"] in ("table", "search")
     assert attrs["spgemm.bcsr"] == {"bs": 8, "nnzb": Lb.nnzb}
     assert attrs["spgemm.chunk"] == {"chunks": 1, "planes": 2,
                                      "steps": entries}
